@@ -46,14 +46,13 @@ import jax
 import numpy as np
 
 from repro.cluster.spec import disagg_testbed, paper_testbed
-from repro.configs import get
 from repro.core.fitness import EvalConfig, TraceEvaluator
 from repro.core.nsga2 import NSGA2, NSGA2Config
 from repro.core.policies import get_policy
 from repro.core.policy import PAPER_DEFAULTS
 from repro.faults import FaultSchedule, TransientErrors, node_available_np, \
     node_slowdown_np
-from repro.models import lm
+from repro.launch.serve import build_models
 from repro.serving import ClusterServer, EngineConfig, ResilienceConfig, \
     ServeRequest
 from repro.workload.sessions import SessionConfig, build_session_trace
@@ -150,17 +149,6 @@ def _analytic_row(scenario: str, config: str, avail: float, s: dict):
 # ---------------------------------------------------------------------------
 # serving layer: live ClusterServer under the same schedules
 # ---------------------------------------------------------------------------
-def _builders():
-    big = get("stablelm-3b").smoke()
-    small = get("qwen3-1.7b").smoke()
-    pb = lm.init(jax.random.key(0), big)
-    ps = lm.init(jax.random.key(1), small)
-    return {"gemma3:27b": (big, pb),
-            "qwen2.5:1.5b-instruct": (small, ps),
-            "qwen2.5-coder:1.5b-instruct": (small, ps),
-            "qwen2.5-math:1.5b-instruct": (small, ps)}
-
-
 def _ecfg(**over):
     kw = dict(max_slots=2, max_seq=48, max_new_tokens=4, prefix_cache=True,
               block_size=8, cache_blocks=32)
@@ -393,7 +381,7 @@ def _overload(rows, bench, builders):
 # ---------------------------------------------------------------------------
 def run(seed: int = 0):
     rows, bench = [], {"smoke": SMOKE}
-    builders = _builders()
+    builders = build_models()
     tuned_genome = _crash_storm(rows, bench, builders)
     _link_flap(rows, bench, builders)
     _straggler(rows, bench, builders, tuned_genome)

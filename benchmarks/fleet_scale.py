@@ -28,13 +28,11 @@ from __future__ import annotations
 import sys
 import time
 
-import jax
 import numpy as np
 
 from repro.cluster.spec import fleet_testbed
-from repro.configs import get
 from repro.core.policy import PAPER_DEFAULTS
-from repro.models import lm
+from repro.launch.serve import build_models
 from repro.serving import ClusterServer, EngineConfig, ServeRequest
 from repro.workload.trace import build_trace
 
@@ -51,20 +49,6 @@ MAX_NEW = 2
 
 ECFG = EngineConfig(max_slots=4, max_seq=32, max_new_tokens=MAX_NEW,
                     prefill_bucket=16)
-
-
-def _builders():
-    """Two real tiny models for the testbed's four names; the three edge
-    names share ONE (cfg, params) identity so all edge engines form a
-    single cohort (the grouping rule in docs/architecture.md)."""
-    big = get("stablelm-3b").smoke()
-    small = get("qwen3-1.7b").smoke()
-    pb = lm.init(jax.random.key(0), big)
-    ps = lm.init(jax.random.key(1), small)
-    return {"gemma3:27b": (big, pb),
-            "qwen2.5:1.5b-instruct": (small, ps),
-            "qwen2.5-coder:1.5b-instruct": (small, ps),
-            "qwen2.5-math:1.5b-instruct": (small, ps)}
 
 
 def _server(cluster, builders, fleet=True):
@@ -128,7 +112,7 @@ def replay(srv, reqs, n_sessions: int, rate: int) -> dict:
 
 
 def run(seed: int = 7):
-    builders = _builders()
+    builders = build_models()
     reqs = build_trace(TRACE_POOL, seed=seed).requests
     rows, bench = [], {}
 

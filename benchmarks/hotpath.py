@@ -202,6 +202,9 @@ def bench_sharded():
         src = os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "src")
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        # the workers fabricate host devices; on a TPU machine the parent
+        # already holds the chip, which a child could not open
+        env["JAX_PLATFORMS"] = "cpu"
         cmd = [sys.executable, "-m", "benchmarks.hotpath",
                "--worker-ndev", str(ndev)] + (["--smoke"] if SMOKE else [])
         proc = subprocess.run(cmd, env=env, capture_output=True, text=True,

@@ -24,12 +24,9 @@ from __future__ import annotations
 import sys
 import time
 
-import jax
-
 from repro.cluster.spec import fleet_testbed
-from repro.configs import get
 from repro.core.policy import PAPER_DEFAULTS
-from repro.models import lm
+from repro.launch.serve import build_models
 from repro.obs import AuditLog, MetricsRegistry, Obs, Tracer, chrome_trace
 from repro.serving import ClusterServer, EngineConfig, ServeRequest
 from repro.workload.trace import build_trace
@@ -46,19 +43,6 @@ MAX_NEW = 2
 
 ECFG = EngineConfig(max_slots=4, max_seq=32, max_new_tokens=MAX_NEW,
                     prefill_bucket=16)
-
-
-def _builders():
-    """Two real tiny models over the testbed's four names (edge names share
-    one identity so the edge engines form a single cohort)."""
-    big = get("stablelm-3b").smoke()
-    small = get("qwen3-1.7b").smoke()
-    pb = lm.init(jax.random.key(0), big)
-    ps = lm.init(jax.random.key(1), small)
-    return {"gemma3:27b": (big, pb),
-            "qwen2.5:1.5b-instruct": (small, ps),
-            "qwen2.5-coder:1.5b-instruct": (small, ps),
-            "qwen2.5-math:1.5b-instruct": (small, ps)}
 
 
 def _full_obs() -> Obs:
@@ -106,7 +90,7 @@ def replay(srv, reqs, n_sessions: int, rate: int) -> dict:
 
 
 def run(seed: int = 7):
-    builders = _builders()
+    builders = build_models()
     reqs = build_trace(TRACE_POOL, seed=seed).requests
     cluster = fleet_testbed(n_edge=6, n_cloud=2)
     suffix = "_smoke" if SMOKE else ""

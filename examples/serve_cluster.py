@@ -5,14 +5,10 @@ and straggler hedging.
 
     PYTHONPATH=src python examples/serve_cluster.py
 """
-import subprocess
-import sys
+from repro.launch.serve import main
 
 if __name__ == "__main__":
     # the launcher is the real entry point; this example drives it with a
     # failure injection so the fault-tolerance path is exercised visibly
-    sys.exit(subprocess.call(
-        [sys.executable, "-m", "repro.launch.serve",
-         "--requests", "24", "--optimize-router",
-         "--fail-node", "1", "--fail-at", "8"],
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin:/usr/local/bin"}))
+    main(["--requests", "24", "--optimize-router",
+          "--fail-node", "1", "--fail-at", "8"])

@@ -742,10 +742,10 @@ def population_mesh(n_devices: Optional[int] = None):
     On a CPU host, fabricate N devices by exporting
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before* the
     first jax import (see benchmarks/hotpath.py for the recipe)."""
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType, Mesh
     devs = jax.devices()
     n = len(devs) if n_devices is None else min(int(n_devices), len(devs))
-    return Mesh(np.asarray(devs[:n]), ("pop",))
+    return Mesh(np.asarray(devs[:n]), ("pop",), axis_types=(AxisType.Auto,))
 
 
 @functools.lru_cache(maxsize=128)
@@ -758,16 +758,15 @@ def _sharded_fitness_kernel(policy: str, objectives: str, cfg: EvalConfig,
     surplus rows are dropped from the outputs); tables/arrays are
     replicated. Per-genome evaluation is embarrassingly parallel, so the
     sharded result equals the single-device vmap."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PSpec
 
     base = _fitness_kernel(policy, objectives, cfg, n_slots, n_groups)
     axis = mesh.axis_names[0]
     ndev = int(np.prod(list(mesh.shape.values())))
-    sharded = shard_map(base, mesh=mesh,
-                        in_specs=(PSpec(axis), PSpec(), PSpec()),
-                        out_specs=(PSpec(axis), PSpec(axis)),
-                        check_rep=False)
+    sharded = jax.shard_map(base, mesh=mesh,
+                            in_specs=(PSpec(axis), PSpec(), PSpec()),
+                            out_specs=(PSpec(axis), PSpec(axis)),
+                            check_vma=False)
 
     def kernel(genomes, key, data):
         P = genomes.shape[0]

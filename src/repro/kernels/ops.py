@@ -1,14 +1,18 @@
 """Public jit'd wrappers for the Pallas kernels with backend dispatch.
 
-On TPU the Pallas kernels run natively; elsewhere (this CPU container, tests)
-they run through ``interpret=True`` or fall back to the jnp reference —
-controlled by ``mode``:
+On TPU the Pallas kernels run natively; elsewhere (CPU hosts, tests) they
+run through ``interpret=True`` or the jnp reference — controlled by
+``mode``:
 
 * "auto"      — Pallas on TPU, reference otherwise (the model zoo default,
                  so dry-runs lower the XLA path and real TPUs get kernels);
 * "pallas"    — force the kernel (native);
 * "interpret" — force the kernel in interpret mode (kernel-correctness tests);
 * "ref"       — force the jnp oracle.
+
+A kernel path never drops to the reference on its own: an axis the
+requested tile does not divide gets the largest tile that does, and a
+length no tile divides raises.
 """
 from __future__ import annotations
 
@@ -35,6 +39,18 @@ def _resolve(mode: str) -> str:
     return mode
 
 
+def _tile(n: int, want: int) -> int:
+    """Largest tile <= ``want`` that divides ``n``: the whole axis when it
+    fits, else a multiple of 8 (the TPU sublane tile)."""
+    if n <= want:
+        return n
+    for b in range(want - want % 8, 7, -8):
+        if n % b == 0:
+            return b
+    raise ValueError(f"no tile <= {want} that is a multiple of 8 divides "
+                     f"length {n}; pad the sequence to a multiple of 8")
+
+
 def dominance_matrix(F: jax.Array, mode: str = "auto") -> jax.Array:
     m = _resolve(mode)
     if m == "ref":
@@ -54,22 +70,22 @@ def flash_attention(q, k, v, causal: bool = True, mode: str = "auto",
                     block_q: int = _fa.DEFAULT_BLOCK_Q,
                     block_k: int = _fa.DEFAULT_BLOCK_K) -> jax.Array:
     m = _resolve(mode)
-    S = q.shape[2]
-    if m == "ref" or S % min(block_q, S) or S % min(block_k, S):
+    if m == "ref":
         return ref.mha_prefill(q, k, v, causal=causal)
-    return _fa.flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k,
+    S = q.shape[2]
+    return _fa.flash_attention(q, k, v, causal=causal,
+                               block_q=_tile(S, block_q),
+                               block_k=_tile(S, block_k),
                                interpret=(m == "interpret"))
 
 
 def gqa_decode_attention(q, k_cache, v_cache, kv_len, mode: str = "auto",
                          block_k: int = _dec.DEFAULT_BLOCK_K) -> jax.Array:
     m = _resolve(mode)
-    Smax = k_cache.shape[2]
-    if m == "ref" or Smax % min(block_k, Smax):
+    if m == "ref":
         return ref.gqa_decode(q, k_cache, v_cache, kv_len)
     return _dec.gqa_decode_attention(q, k_cache, v_cache, kv_len,
-                                     block_k=block_k,
+                                     block_k=_tile(k_cache.shape[2], block_k),
                                      interpret=(m == "interpret"))
 
 

@@ -1,10 +1,4 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# NOTE: the two lines above MUST precede every other import (including any
-# `from repro...`) — jax locks the device count at first initialization.
-
-__doc__ = """Multi-pod dry-run (deliverable e).
+"""Multi-pod dry-run (deliverable e).
 
 For every (architecture × input-shape × mesh) cell: AOT-lower and compile the
 appropriate step function (train_step / prefill_step / decode_step) against
@@ -17,8 +11,9 @@ ShapeDtypeStruct inputs on the production mesh, then record
 into results/dryrun/<arch>__<shape>__<mesh>.json. These JSONs are the sole
 input to benchmarks/roofline.py (§Roofline) and EXPERIMENTS.md §Dry-run.
 
-NOTE the import order above: XLA_FLAGS must be set before jax initializes,
-and only in this entrypoint — tests and benches see the real single device.
+``main`` fabricates 512 host devices before jax first initializes its
+backends, and only in this entrypoint: importing the module changes nothing,
+so tests and benches see the real devices.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch stablelm-3b \
@@ -341,6 +336,7 @@ def main():
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    jax.config.update("jax_num_cpu_devices", 512)
 
     archs = all_ids() if args.all or args.arch is None else [args.arch]
     shapes = list(SHAPES) if args.all or args.shape is None else [args.shape]

@@ -1,8 +1,8 @@
 """Production mesh definitions.
 
 ``make_production_mesh`` is a function (never a module-level constant) so
-importing this module touches no jax device state; the dry-run sets
-XLA_FLAGS before any jax import to fabricate 512 host devices.
+importing this module touches no jax device state; the dry-run's ``main``
+fabricates 512 host devices before jax initializes its backends.
 
 Target hardware: TPU v5e pods — 256 chips/pod in a (16, 16) 2-D ICI torus;
 multi-pod spans 2 pods over DCN. Axis roles:
@@ -13,6 +13,7 @@ multi-pod spans 2 pods over DCN. Axis roles:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # v5e roofline constants (per chip) — used by benchmarks/roofline.py
 PEAK_FLOPS_BF16 = 197e12      # FLOP/s
@@ -24,7 +25,7 @@ ICI_LINKS_2D = 4              # 2-D torus: 4 links/chip on v5e
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 2, data: int = None):
@@ -32,4 +33,5 @@ def make_host_mesh(model: int = 2, data: int = None):
     n = len(jax.devices())
     model = min(model, n)
     data = data or n // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         (AxisType.Auto,) * 2)

@@ -150,6 +150,15 @@ class _Transfer:
     complexity: float = 0.0
 
 
+def _is_jax_error(e: BaseException) -> bool:
+    """A compile, lowering or device runtime error from JAX or its Pallas
+    backend (a kernel Mosaic refuses, an HBM OOM). That is a fault of the
+    program, not of the node: turning it into a node crash and a reroute
+    would serve the request elsewhere and hide it, so it propagates."""
+    return any(c.__module__.split(".")[0] in ("jax", "jaxlib")
+               for c in type(e).__mro__)
+
+
 class ClusterServer:
     def __init__(self, cluster: ClusterSpec, model_builders: Dict[str, tuple],
                  thresholds, engine_cfg: EngineConfig = EngineConfig(),
@@ -631,7 +640,9 @@ class ClusterServer:
                 self.engines[tr.decode_pair].import_kv(
                     tr.tokens[:tr.n_cov], tr.payload)
                 self._dispatch(tr.sreq, tr.decode_pair)
-            except Exception:
+            except Exception as e:
+                if _is_jax_error(e):
+                    raise
                 # delivery blew up mid-import: the decode pool may hold a
                 # partial landing, so crash the node (flushes its pools back
                 # to the refcount baseline, reroutes its flights) and send
@@ -700,7 +711,9 @@ class ClusterServer:
                     retired = eng._commit_chunk(chunk_work[pair])
                 else:
                     retired = eng.step_n(chunk) if chunk > 1 else eng.step()
-            except Exception:
+            except Exception as e:
+                if _is_jax_error(e):
+                    raise
                 # exception safety: an error mid-commit must not leak export
                 # pins or cohort write-backs. Treat it as a node crash —
                 # fail_node cancels this node's flights, re-routes them, and
@@ -861,6 +874,9 @@ class ClusterServer:
                 "transfers_inflight": len(self.transfers),
                 "cancelled": sum(s.total_cancelled
                                  for s in self.monitor.stats.values()),
+                "failed": sum(s.total_failed
+                              for s in self.monitor.stats.values()),
+                "down_nodes": sorted(self._down_nodes),
                 "queue_lengths": self.monitor.queue_lengths(),
                 "active": self.active_count,
                 "queued": self.queue_len,

@@ -20,25 +20,23 @@ REPO = Path(__file__).resolve().parent.parent
 
 
 def _run_subprocess(code: str) -> str:
-    return subprocess.run(
+    """Run ``code`` on 8 fabricated CPU devices; returns stdout followed by
+    stderr, so a failed assertion shows the child's traceback."""
+    proc = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         env={"PYTHONPATH": str(REPO / "src"), "PATH": "/usr/bin:/bin",
              "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
              "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True, timeout=900, check=False).stdout
+        capture_output=True, text=True, timeout=900, check=False)
+    return proc.stdout + proc.stderr
 
 
 # ---------------------------------------------------------------------------
 # sharding rules (pure functions, no devices needed)
 # ---------------------------------------------------------------------------
 def _fake_mesh():
-    # an abstract mesh object is enough for spec derivation; the
-    # AbstractMesh signature changed across jax releases (axis_sizes +
-    # axis_names vs a tuple of (name, size) pairs), so accept either
-    try:
-        return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    except TypeError:
-        return jax.sharding.AbstractMesh((("data", 16), ("model", 16)))
+    # an abstract mesh object is enough for spec derivation
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_param_specs_cover_all_leaves_and_divide():
@@ -93,11 +91,12 @@ def test_constrain_noop_without_mesh():
 def test_make_production_mesh_shapes():
     out = _run_subprocess("""
         import jax
-        from repro.launch.mesh import make_production_mesh
+        from jax.sharding import AxisType
         # reduced: 8 devices -> (4, 2) and (2, 2, 2)
-        m = jax.make_mesh((4, 2), ("data", "model"))
+        m = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
         print(dict(m.shape))  # dict(): repr is stable across jax versions
-        m2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        m2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                           (AxisType.Auto,) * 3)
         print(dict(m2.shape))
     """)
     assert "'data': 4" in out and "'model': 2" in out
@@ -109,12 +108,13 @@ def test_sharded_train_step_compiles_and_runs_8dev():
     numerically matching the single-device step."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.configs import get
         from repro.models import lm, sharding as shard
         from repro.training.optim import adamw, OptConfig
 
         cfg = get("stablelm-3b").smoke()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
         params = lm.init(jax.random.key(0), cfg)
         opt = adamw(OptConfig(lr=1e-3))
         state = opt.init(params)
@@ -150,11 +150,12 @@ def test_dryrun_cell_reduced_mesh():
     """The dry-run machinery end-to-end on a small fabricated mesh."""
     out = _run_subprocess("""
         import jax, dataclasses
+        from jax.sharding import AxisType
         from repro.configs import get
         from repro.launch import dryrun
         from repro.models import sharding as shard
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
         cfg = dataclasses.replace(get("qwen3-1.7b").config(), n_layers=4)
         fn, args, outsh, extra = dryrun.build_cell(cfg, "train_4k", mesh,
                                                    unroll=False)
@@ -162,8 +163,6 @@ def test_dryrun_cell_reduced_mesh():
             jitted = jax.jit(fn, out_shardings=outsh)
             compiled = jitted.lower(*args).compile()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0]
         print("flops", cost.get("flops", 0) > 0)
         coll = dryrun.collective_bytes(compiled.as_text())
         print("has_collectives", coll["total_bytes"] > 0)
@@ -176,22 +175,18 @@ def test_grad_compression_psum_8dev():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.training.grad_compress import init_residual, psum_compressed
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:  # older jax keeps it in experimental
-            from jax.experimental.shard_map import shard_map
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = jax.make_mesh((8,), ("pod",), (AxisType.Auto,))
         grads = {"w": jnp.arange(512, dtype=jnp.float32).reshape(2, 256) / 77}
         res = init_residual(grads)
 
-        @partial(shard_map, mesh=mesh, in_specs=(), out_specs=P())
+        @partial(jax.shard_map, mesh=mesh, in_specs=(), out_specs=P())
         def reduce_plain():
             return jax.tree.map(lambda g: jax.lax.psum(g, "pod") / 8, grads)
 
-        @partial(shard_map, mesh=mesh, in_specs=(), out_specs=P())
+        @partial(jax.shard_map, mesh=mesh, in_specs=(), out_specs=P())
         def reduce_q():
             m, r = psum_compressed(grads, res, "pod", method="int8")
             return m

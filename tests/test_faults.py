@@ -14,7 +14,6 @@ from conftest import (crash_storm_schedule, link_flap_schedule,
 from repro.cluster.monitor import ClusterMonitor
 from repro.cluster.simulator import ClusterSimulator
 from repro.cluster.spec import paper_testbed
-from repro.configs import get
 from repro.core.fitness import EvalConfig, TraceEvaluator
 from repro.core.policies import get_policy, list_policies, runtime_policies
 from repro.core.policy import PAPER_DEFAULTS
@@ -25,7 +24,7 @@ from repro.faults import (CrashWindow, FaultSchedule, HeartbeatLoss,
                           node_available_jnp, node_available_np,
                           node_slowdown_jnp, node_slowdown_np,
                           transient_delay_jnp, transient_delay_np)
-from repro.models import lm
+from repro.launch.serve import build_models
 from repro.serving import (ClusterServer, EngineConfig, ResilienceConfig,
                            ServeRequest)
 from repro.workload.trace import build_trace
@@ -206,14 +205,7 @@ def test_breaker_half_open_probe_failure_reopens():
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
 def builders():
-    big = get("stablelm-3b").smoke()
-    small = get("qwen3-1.7b").smoke()
-    pb = lm.init(jax.random.key(0), big)
-    ps = lm.init(jax.random.key(1), small)
-    return {"gemma3:27b": (big, pb),
-            "qwen2.5:1.5b-instruct": (small, ps),
-            "qwen2.5-coder:1.5b-instruct": (small, ps),
-            "qwen2.5-math:1.5b-instruct": (small, ps)}
+    return build_models()
 
 
 @pytest.fixture(scope="module")
@@ -406,3 +398,25 @@ def test_phase_b_exception_releases_pins(builders, reqs, monkeypatch):
     assert srv.stats()["reroutes"] >= 1
     _assert_conserved(srv)
     _assert_no_leaks(srv)
+
+
+@pytest.mark.parametrize("err", [
+    jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM"),
+    pytest.param("mosaic", id="LoweringException")])
+def test_phase_b_jax_error_propagates(builders, reqs, monkeypatch, err):
+    """A compile or device error is a program fault, not a node fault: it
+    escapes ``run`` instead of becoming a crash and a silent reroute."""
+    if err == "mosaic":
+        from jax._src.pallas.mosaic.lowering import LoweringException
+        err = LoweringException("Invalid relayout")
+    srv = _server(builders)
+    for i, r in enumerate(reqs[:4]):
+        srv.submit(ServeRequest(request_id=i, req=r, max_new_tokens=4))
+    for eng in srv.engines.values():
+        def boom(*a, **k):
+            raise err
+        monkeypatch.setattr(eng, "_commit_chunk", boom)
+        monkeypatch.setattr(eng, "step", boom)
+    with pytest.raises(type(err)):
+        srv.run(max_ticks=50)
+    assert not srv._down_nodes and srv.stats()["reroutes"] == 0

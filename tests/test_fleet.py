@@ -11,15 +11,13 @@ only ever touches the *discarded* copy (see docs/architecture.md).
 """
 import dataclasses
 
-import jax
 import numpy as np
 import pytest
 
 from repro.cluster.spec import disagg_testbed, fleet_testbed, paper_testbed
-from repro.configs import get
 from repro.core.policy import PAPER_DEFAULTS
 from repro.core.policies import runtime_policies
-from repro.models import lm
+from repro.launch.serve import build_models
 from repro.serving import ClusterServer, EngineConfig, LLMEngine, ServeRequest
 from repro.serving import fleet as fleet_mod
 from repro.workload.trace import build_trace
@@ -28,23 +26,16 @@ NO_HEDGE = 10**9
 
 
 @pytest.fixture(scope="module")
-def tiny_model():
-    cfg = get("stablelm-3b").smoke()
-    return cfg, lm.init(jax.random.key(0), cfg)
-
-
-@pytest.fixture(scope="module")
-def builders(tiny_model):
+def builders():
     """Two real tiny models standing in for the testbed's 4 names: the
     three edge names share ONE (cfg, params) pair, so all edge engines
     collapse into a single cohort."""
-    big, pb = tiny_model
-    small = get("qwen3-1.7b").smoke()
-    ps = lm.init(jax.random.key(1), small)
-    return {"gemma3:27b": (big, pb),
-            "qwen2.5:1.5b-instruct": (small, ps),
-            "qwen2.5-coder:1.5b-instruct": (small, ps),
-            "qwen2.5-math:1.5b-instruct": (small, ps)}
+    return build_models()
+
+
+@pytest.fixture(scope="module")
+def tiny_model(builders):
+    return builders["gemma3:27b"]
 
 
 @pytest.fixture(scope="module")

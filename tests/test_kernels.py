@@ -264,3 +264,27 @@ def test_ops_interpret_equals_ref_for_attention():
     b = ops.flash_attention(q, k, v, mode="ref")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
                                rtol=2e-5)
+
+
+@pytest.mark.parametrize("n, want, tile", [(48, 512, 48), (768, 512, 384),
+                                           (1000, 128, 40)])
+def test_ops_tile_divides_instead_of_falling_back(n, want, tile):
+    assert ops._tile(n, want) == tile
+    q = jax.random.normal(jax.random.key(0), (2, 4, 32))
+    kc = jax.random.normal(jax.random.key(1), (2, 2, n, 32))
+    vc = jax.random.normal(jax.random.key(2), (2, 2, n, 32))
+    kv_len = jnp.asarray([n // 3, n], jnp.int32)
+    a = ops.gqa_decode_attention(q, kc, vc, kv_len, mode="interpret",
+                                 block_k=want)
+    b = ops.gqa_decode_attention(q, kc, vc, kv_len, mode="ref")
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                               rtol=2e-5)
+
+
+def test_ops_kernel_path_raises_when_no_tile_divides():
+    q = jnp.zeros((1, 4, 16, 32))
+    kv = jnp.zeros((1, 2, 1030, 32))
+    with pytest.raises(ValueError, match="divides"):
+        ops.gqa_decode_attention(q[:, :, 0], kv, kv,
+                                 jnp.asarray([4], jnp.int32),
+                                 mode="interpret")

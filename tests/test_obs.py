@@ -377,24 +377,10 @@ def test_des_noop_default_changes_nothing():
 # ---------------------------------------------------------------------------
 # serving: span/monitor mirror across fleet, failover, hedging, handoff
 # ---------------------------------------------------------------------------
-def _serve_builders():
-    import jax
-
-    from repro.configs import get
-    from repro.models import lm
-    big = get("stablelm-3b").smoke()
-    small = get("qwen3-1.7b").smoke()
-    pb = lm.init(jax.random.key(0), big)
-    ps = lm.init(jax.random.key(1), small)
-    return {"gemma3:27b": (big, pb),
-            "qwen2.5:1.5b-instruct": (small, ps),
-            "qwen2.5-coder:1.5b-instruct": (small, ps),
-            "qwen2.5-math:1.5b-instruct": (small, ps)}
-
-
 @pytest.fixture(scope="module")
 def serve_parts():
-    return shared_cluster(), _serve_builders(), build_trace(24, seed=5)
+    from repro.launch.serve import build_models
+    return shared_cluster(), build_models(), build_trace(24, seed=5)
 
 
 def _event_counts(tracer):

@@ -8,6 +8,7 @@ import pytest
 from repro.cluster.spec import paper_testbed
 from repro.configs import get
 from repro.core.policy import PAPER_DEFAULTS
+from repro.launch.serve import build_models
 from repro.models import lm
 from repro.serving import ClusterServer, EngineConfig, LLMEngine, ServeRequest
 from repro.workload.trace import build_trace
@@ -83,23 +84,9 @@ def test_engine_ragged_lengths_independent(tiny_model):
 # ---------------------------------------------------------------------------
 # cluster server
 # ---------------------------------------------------------------------------
-def _builders():
-    """Tiny real models standing in for the testbed's 4 model types."""
-    big = get("stablelm-3b").smoke()
-    small = get("qwen3-1.7b").smoke()
-    kb = jax.random.key(0)
-    ks = jax.random.key(1)
-    pb = lm.init(kb, big)
-    ps = lm.init(ks, small)
-    return {"gemma3:27b": (big, pb),
-            "qwen2.5:1.5b-instruct": (small, ps),
-            "qwen2.5-coder:1.5b-instruct": (small, ps),
-            "qwen2.5-math:1.5b-instruct": (small, ps)}
-
-
 @pytest.fixture(scope="module")
 def server_parts():
-    return paper_testbed(), _builders(), build_trace(24, seed=5)
+    return paper_testbed(), build_models(), build_trace(24, seed=5)
 
 
 def test_cluster_server_serves_all(server_parts):

@@ -167,7 +167,8 @@ def test_checkpoint_elastic_reshard(tmp_path):
     tree = {"w": jnp.arange(32, dtype=jnp.float32).reshape(4, 8)}
     specs = {"w": P(None, "model")}
     save_checkpoint(tmp_path, 0, tree, specs=specs)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         (jax.sharding.AxisType.Auto,) * 2)
     restored, manifest = load_checkpoint(tmp_path, tree, mesh=mesh)
     np.testing.assert_array_equal(np.asarray(restored["w"]),
                                   np.asarray(tree["w"]))
