@@ -32,6 +32,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels import ops
 from .pareto import crowding_distance, non_dominated_sort
 
 PENALTY = 1e6
@@ -308,14 +309,12 @@ def _call_fitness(fitness_fn, genomes, key, data):
 
 
 def _dominance_matrix_for(F, dominance: str):
-    """Resolve the survival dominance matrix backend ("jnp" -> None, i.e.
-    the reference inside non_dominated_sort; "pallas" -> the kernel,
-    interpret-mode off TPU)."""
-    if dominance == "jnp":
+    """The survival dominance matrix for ``dominance``, a resolved
+    ``kernels.ops`` mode: None for "ref" (non_dominated_sort computes the
+    same reference itself), the Pallas kernel otherwise."""
+    if dominance == "ref":
         return None
-    from ..kernels.dominance import dominance_matrix_pallas
-    interpret = jax.default_backend() != "tpu"
-    return dominance_matrix_pallas(F, interpret=interpret).astype(bool)
+    return ops.dominance_matrix(F, mode=dominance)
 
 
 def _init_core(key, lo, hi, archive, fitness_data, scfg: _StaticConfig,
@@ -383,7 +382,7 @@ def _step_core(state: NSGA2State, lo, hi, fitness_data,
     F_all = jnp.concatenate([state.F, F_off], axis=0)
     F_raw_all = jnp.concatenate([state.F_raw, F_off_raw], axis=0)
     viol_all = jnp.concatenate([state.violation, viol_off], axis=0)
-    dom_fn = (None if dominance == "jnp"
+    dom_fn = (None if dominance == "ref"
               else lambda F: _dominance_matrix_for(F, dominance))
     sel, rank_sel, crowd_sel = survival_select(F_all, P, dom_fn, top=P)
 
@@ -440,20 +439,29 @@ class NSGA2:
         ``evolve_scan(..., archive=)`` (a dynamic argument, so repeated
         warm-started re-fits share one compiled executable) over the legacy
         :func:`archive_init` closure.
-    use_pallas_dominance : compute the survival-selection dominance matrix
-        with the Pallas kernel (``repro.kernels.dominance``) — native on TPU,
-        interpreter mode elsewhere (CPU tests); semantics are identical to
-        the jnp reference (parity-tested in tests/test_nsga2.py).
+    use_pallas_dominance : how the survival-selection dominance matrix is
+        computed. None (default): the Pallas kernel
+        (``repro.kernels.dominance``) on a TPU and the jnp reference
+        elsewhere, as ``kernels.ops`` mode "auto" decides; True: the kernel
+        everywhere, interpreted off a TPU (CPU parity tests); False: the jnp
+        reference. Semantics are identical (parity-tested in
+        tests/test_nsga2.py).
     """
 
     def __init__(self, fitness_fn: FitnessFn, config: NSGA2Config,
                  init_fn: Optional[Callable[[jax.Array], jax.Array]] = None,
-                 use_pallas_dominance: bool = False):
+                 use_pallas_dominance: Optional[bool] = None):
         self.fitness_fn = fitness_fn
         self.config = config
         self.init_fn = init_fn
         self.use_pallas_dominance = use_pallas_dominance
-        self._dominance = "pallas" if use_pallas_dominance else "jnp"
+        auto = ops.resolve("auto")    # "pallas" on a TPU, "ref" elsewhere
+        if use_pallas_dominance is None:
+            self._dominance = auto
+        elif use_pallas_dominance:
+            self._dominance = "pallas" if auto == "pallas" else "interpret"
+        else:
+            self._dominance = "ref"
         # cache-friendly split: fitness callables built by
         # core.fitness.make_fitness carry a memoized module-level `.kernel`
         # plus a `.data` pytree — the kernel identity is the jit cache key,

@@ -32,7 +32,8 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _resolve(mode: str) -> str:
+def resolve(mode: str) -> str:
+    """The mode a kernel call in ``mode`` runs in on this backend."""
     assert mode in _MODES, mode
     if mode == "auto":
         return "pallas" if _on_tpu() else "ref"
@@ -52,7 +53,7 @@ def _tile(n: int, want: int) -> int:
 
 
 def dominance_matrix(F: jax.Array, mode: str = "auto") -> jax.Array:
-    m = _resolve(mode)
+    m = resolve(mode)
     if m == "ref":
         return ref.dominance_matrix(F)
     out = _dom.dominance_matrix_pallas(F, interpret=(m == "interpret"))
@@ -60,7 +61,7 @@ def dominance_matrix(F: jax.Array, mode: str = "auto") -> jax.Array:
 
 
 def dominance_counts(F: jax.Array, mode: str = "auto") -> jax.Array:
-    m = _resolve(mode)
+    m = resolve(mode)
     if m == "ref":
         return ref.dominance_counts(F)
     return _dom.dominance_counts_pallas(F, interpret=(m == "interpret"))
@@ -69,7 +70,7 @@ def dominance_counts(F: jax.Array, mode: str = "auto") -> jax.Array:
 def flash_attention(q, k, v, causal: bool = True, mode: str = "auto",
                     block_q: int = _fa.DEFAULT_BLOCK_Q,
                     block_k: int = _fa.DEFAULT_BLOCK_K) -> jax.Array:
-    m = _resolve(mode)
+    m = resolve(mode)
     if m == "ref":
         return ref.mha_prefill(q, k, v, causal=causal)
     S = q.shape[2]
@@ -81,7 +82,7 @@ def flash_attention(q, k, v, causal: bool = True, mode: str = "auto",
 
 def gqa_decode_attention(q, k_cache, v_cache, kv_len, mode: str = "auto",
                          block_k: int = _dec.DEFAULT_BLOCK_K) -> jax.Array:
-    m = _resolve(mode)
+    m = resolve(mode)
     if m == "ref":
         return ref.gqa_decode(q, k_cache, v_cache, kv_len)
     return _dec.gqa_decode_attention(q, k_cache, v_cache, kv_len,
@@ -89,12 +90,20 @@ def gqa_decode_attention(q, k_cache, v_cache, kv_len, mode: str = "auto",
                                      interpret=(m == "interpret"))
 
 
+def check_decode_length(n: int, mode: str = "auto") -> None:
+    """Raise now, rather than at the first trace, if
+    :func:`gqa_decode_attention` in ``mode`` cannot tile a cache of ``n``
+    positions."""
+    if resolve(mode) != "ref":
+        _tile(n, _dec.DEFAULT_BLOCK_K)
+
+
 def paged_gqa_decode_attention(q, k_pool, v_pool, block_table, kv_len,
                                mode: str = "auto") -> jax.Array:
     """Decode attention over a paged KV pool addressed by a per-request
     block table (see ``serving.kvcache``). Pool-resident decode path for
     prefix-reuse serving on TPU; jnp gather+reference elsewhere."""
-    m = _resolve(mode)
+    m = resolve(mode)
     if m == "ref":
         return ref.paged_gqa_decode(q, k_pool, v_pool, block_table, kv_len)
     return _paged.paged_gqa_decode_attention(q, k_pool, v_pool, block_table,

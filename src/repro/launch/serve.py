@@ -39,37 +39,34 @@ def enable_compile_cache() -> None:
         jax.config.update("jax_compilation_cache_dir", str(REPO / ".jax_cache"))
 
 
-def build_models(big=None, small=None, seed: int = 0):
+def build_models(big=None, small=None):
     """Model name -> (ModelConfig, params) for the paper testbed's four
     model names. ``big`` serves ``gemma3:27b``; the three qwen2.5 names
     share ``small`` and ONE params pytree, so their engines form one fleet
     cohort. Defaults are the ``.smoke()`` widths of stablelm-3b and
-    qwen3-1.7b. Weights are random from ``seed``; the init is jitted so a
+    qwen3-1.7b. Weights are random from fixed seeds; the init is jitted so a
     published-width model is written to device memory once, without a
     second stacked copy."""
     big = get("stablelm-3b").smoke() if big is None else big
     small = get("qwen3-1.7b").smoke() if small is None else small
     init = jax.jit(lm.init, static_argnums=1)
-    pb = init(jax.random.key(seed), big)
-    ps = init(jax.random.key(seed + 1), small)
+    pb = init(jax.random.key(0), big)
+    ps = init(jax.random.key(1), small)
     return {"gemma3:27b": (big, pb),
             "qwen2.5:1.5b-instruct": (small, ps),
             "qwen2.5-coder:1.5b-instruct": (small, ps),
             "qwen2.5-math:1.5b-instruct": (small, ps)}
 
 
-def tune_router(cluster, trace, generations: int = 30, pop_size: int = 48,
-                seed: int = 0):
+def tune_router(cluster, trace, generations: int = 30, pop_size: int = 48):
     """NSGA-II over the paper's threshold genome on ``trace``; returns the
     genome the paper's equal-weight Eq. (1) selects and its objectives
-    (RQ, C, RT). On a TPU the survival sort uses the Pallas dominance
-    kernel."""
+    (RQ, C, RT)."""
     ev = TraceEvaluator(trace, cluster, EvalConfig(concurrency=4))
     cfg = NSGA2Config(pop_size=pop_size, n_generations=generations,
                       lo=jnp.asarray(BOUNDS_LO), hi=jnp.asarray(BOUNDS_HI))
-    opt = NSGA2(ev.make_fitness("threshold"), cfg,
-                use_pallas_dominance=jax.default_backend() == "tpu")
-    state = opt.evolve_scan(jax.random.key(seed), generations)
+    opt = NSGA2(ev.make_fitness("threshold"), cfg)
+    state = opt.evolve_scan(jax.random.key(0), generations)
     return opt.select_by_weights(state, jnp.full((3,), 1 / 3))
 
 

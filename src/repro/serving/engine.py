@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import ops as kops
 from ..models import lm
 from ..models.config import ModelConfig
 from .fleet import ChunkWork, FleetMemberStore, decode_chunk_body
@@ -136,6 +137,10 @@ class LLMEngine:
         self.cfg = cfg
         self.params = params
         self.ecfg = ecfg
+        # decode attention tiles the max_seq cache axis: a length it cannot
+        # tile fails here, not mid-serve where it would pass for a node fault
+        if any(m.startswith("attn") for m, _ in cfg.pattern):
+            kops.check_decode_length(ecfg.max_seq)
         B = ecfg.max_slots
         # device state lives behind a store: engine-local arrays until a
         # fleet Cohort adopts this engine, a member view into the cohort's

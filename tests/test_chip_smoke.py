@@ -45,10 +45,8 @@ def test_tune_and_serve_phases_at_smoke_widths(chip_smoke, monkeypatch):
         config=get(name).smoke))
     smoke = chip_smoke.Smoke()
     cluster, trace = paper_testbed(), build_trace(64, seed=0)
-    thresholds = smoke.phase("tune", chip_smoke.tune, smoke, cluster, trace,
-                             0)
-    smoke.phase("serve", chip_smoke.serve, smoke, cluster, trace, thresholds,
-                0)
+    thresholds = smoke.phase("tune", chip_smoke.tune, smoke, cluster, trace)
+    smoke.phase("serve", chip_smoke.serve, smoke, cluster, trace, thresholds)
     assert smoke.failures == []
 
 
@@ -63,11 +61,11 @@ def test_kernel_phase_in_interpret_mode(chip_smoke, monkeypatch):
             "gqa_decode_attention", "dominance_matrix",
             "dominance_counts")}))
     smoke = chip_smoke.Smoke()
-    smoke.phase("kernels", chip_smoke.kernels, smoke, 0)
+    smoke.phase("kernels", chip_smoke.kernels, smoke)
     assert smoke.failures == []
 
 
 def test_sharded_fitness_phase_on_one_device(chip_smoke):
     smoke = chip_smoke.Smoke()
-    smoke.phase("sharded_fitness", chip_smoke.sharded_fitness, smoke, 1, 0)
+    smoke.phase("sharded_fitness", chip_smoke.sharded_fitness, smoke, 1)
     assert smoke.failures == []

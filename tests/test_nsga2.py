@@ -10,6 +10,7 @@ from repro.core.nsga2 import (NSGA2, NSGA2Config, binary_tournament,
                               polynomial_mutation, reassignment_mutation,
                               sbx_crossover, survival_select,
                               uniform_swap_crossover)
+from repro.kernels import ops
 
 
 def test_sbx_respects_bounds_and_prob_zero_identity():
@@ -134,6 +135,22 @@ def test_pallas_dominance_flag_matches_reference():
     np.testing.assert_array_equal(np.asarray(ref.rank), np.asarray(pal.rank))
     np.testing.assert_allclose(np.asarray(ref.genomes),
                                np.asarray(pal.genomes), rtol=1e-6)
+
+
+@pytest.mark.parametrize("on_tpu, flag, mode", [
+    (False, None, "ref"), (True, None, "pallas"),
+    (False, True, "interpret"), (True, True, "pallas"),
+    (True, False, "ref")])
+def test_dominance_backend_follows_kernel_ops(monkeypatch, on_tpu, flag,
+                                              mode):
+    """The default takes the kernel exactly where ``kernels.ops`` "auto"
+    does; the flag forces the kernel (interpreted off TPU) or the
+    reference."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: on_tpu)
+    cfg = NSGA2Config(pop_size=8, n_generations=1, lo=jnp.zeros(2),
+                      hi=jnp.ones(2))
+    assert NSGA2(_zdt1_fitness, cfg,
+                 use_pallas_dominance=flag)._dominance == mode
 
 
 def _discrete_fitness(genomes, key):

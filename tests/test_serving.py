@@ -8,6 +8,7 @@ import pytest
 from repro.cluster.spec import paper_testbed
 from repro.configs import get
 from repro.core.policy import PAPER_DEFAULTS
+from repro.kernels import ops
 from repro.launch.serve import build_models
 from repro.models import lm
 from repro.serving import ClusterServer, EngineConfig, LLMEngine, ServeRequest
@@ -99,6 +100,17 @@ def test_cluster_server_serves_all(server_parts):
     done = srv.run()
     assert sorted(done) == list(range(12))
     assert all(len(d["tokens"]) == 3 for d in done.values())
+
+
+def test_untileable_max_seq_fails_at_construction(server_parts, monkeypatch):
+    """Where decode attention runs the kernel, a 1030-position cache has no
+    tile: the server refuses to build instead of serving and turning the
+    trace-time error into node crashes and reroutes."""
+    cluster, builders, _ = server_parts
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    with pytest.raises(ValueError, match="divides"):
+        ClusterServer(cluster, builders, PAPER_DEFAULTS,
+                      EngineConfig(max_slots=2, max_seq=1030))
 
 
 def test_cluster_server_failover_requeues(server_parts):

@@ -26,17 +26,26 @@ SLOTS, MAX_SEQ, COHORT = 4, 512, 8
 def one_chip():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    # a compile for a described chip cannot be read back from the
-    # persistent cache without one: keep these compiles out of it
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # no TPU compiler library on this host
-        pytest.skip(reason=f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    cache_on = jax.config.jax_enable_compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        if "TPU_LOG_DIR" not in os.environ:
+            mp.setenv("TPU_LOG_DIR", "disabled")
+        # a compile for a described chip cannot be read back from the
+        # persistent cache without one: keep these compiles out of it
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            try:
+                topo = topologies.get_topology_desc(platform="tpu",
+                                                    topology_name="v5e:2x2")
+            except Exception as e:  # no TPU compiler library on this host
+                pytest.skip(reason="no v5e:2x2 topology can be described "
+                                   f"here: {e}")
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            # later files on this worker see the process as it was
+            jax.config.update("jax_enable_compilation_cache", cache_on)
+            compilation_cache.reset_cache()
 
 
 def _spec(shape, dtype, sharding):
