@@ -53,6 +53,7 @@ from ..cluster.monitor import ClusterMonitor
 from ..cluster.spec import ClusterArrays, ClusterSpec
 from ..learn import LearnConfig, OnlineEstimator
 from ..learn import estimators as learn_est
+from ..obs.timeline import NOOP_TIMELINE
 from ..workload.classifier import classify
 from ..workload.datasets import Request
 from ..workload.features import complexity_score
@@ -111,7 +112,8 @@ class RequestRouter:
                  cache_block: int = 16,
                  params: Optional[Sequence[float]] = None,
                  audit=None, learned: bool = False,
-                 learner: LearnConfig = LearnConfig()):
+                 learner: LearnConfig = LearnConfig(),
+                 timeline=NOOP_TIMELINE):
         self.policy = get_policy(mode)     # ValueError lists registry names
         if self.policy.genome_spec.per_request:
             raise ValueError(
@@ -142,6 +144,8 @@ class RequestRouter:
         # optional repro.obs.AuditLog: every route() call logs its
         # per-candidate decision breakdown (None = zero overhead)
         self.audit = audit
+        # optional repro.obs.Timeline: each route() is a router.route span
+        self.timeline = timeline
         self._rng = np.random.default_rng(0)
         # numpy view of the pair table, converted once: the per-request hot
         # path must not pay device-to-host transfers on every decision
@@ -221,6 +225,14 @@ class RequestRouter:
         recorded trace-seconds — must pass the same clock here, or the
         tuned window budget is applied on a different time base than the
         one NSGA-II optimized it for."""
+        with self.timeline.span("router.route"):
+            return self._route(req, want_backup, ttft_deadline,
+                               tpot_deadline, now)
+
+    def _route(self, req: Request, want_backup: bool,
+               ttft_deadline: Optional[float],
+               tpot_deadline: Optional[float],
+               now: Optional[float]) -> RouteDecision:
         pol = self.policy
         pred_cat, conf = classify(req, self._rng)
         c_i = complexity_score(req, pred_cat)

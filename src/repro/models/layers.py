@@ -290,28 +290,28 @@ def attention_decode(p, cfg: ModelConfig, x, cache, pos,
     v_new = constrain(v_new, "dp", None, None, "model")
     k_cache, v_cache = cache
     # write at pos (per batch row)
-    k_cache = jax.vmap(
-        lambda c, kn, i: jax.lax.dynamic_update_slice_in_dim(c, kn, i, 0)
-    )(k_cache, k_new, pos)
-    v_cache = jax.vmap(
-        lambda c, vn, i: jax.lax.dynamic_update_slice_in_dim(c, vn, i, 0)
-    )(v_cache, v_new, pos)
+    with jax.named_scope("attn.kv_write"):
+        k_cache = jax.vmap(
+            lambda c, kn, i: jax.lax.dynamic_update_slice_in_dim(c, kn, i, 0)
+        )(k_cache, k_new, pos)
+        v_cache = jax.vmap(
+            lambda c, vn, i: jax.lax.dynamic_update_slice_in_dim(c, vn, i, 0)
+        )(v_cache, v_new, pos)
     kv_len = pos + 1
-
-    if use_pallas in ("pallas", "interpret") or (
-            use_pallas == "auto" and jax.default_backend() == "tpu"):
-        out = kops.gqa_decode_attention(
-            q[:, 0].reshape(B, cfg.n_heads, cfg.hd),
-            jnp.transpose(k_cache, (0, 2, 1, 3)),
-            jnp.transpose(v_cache, (0, 2, 1, 3)), kv_len, mode=use_pallas)
-        out = out.reshape(B, 1, cfg.q_dim)
-    else:
-        from ..kernels import ref as kref
-        out = kref.gqa_decode(
-            q[:, 0].reshape(B, cfg.n_heads, cfg.hd),
-            jnp.transpose(k_cache, (0, 2, 1, 3)),
-            jnp.transpose(v_cache, (0, 2, 1, 3)), kv_len)
-        out = out.reshape(B, 1, cfg.q_dim)
+    # the kernels read the cache heads-major
+    with jax.named_scope("attn.kv_layout"):
+        k_hm = jnp.transpose(k_cache, (0, 2, 1, 3))
+        v_hm = jnp.transpose(v_cache, (0, 2, 1, 3))
+    q1 = q[:, 0].reshape(B, cfg.n_heads, cfg.hd)
+    with jax.named_scope("attn.kernel"):
+        if use_pallas in ("pallas", "interpret") or (
+                use_pallas == "auto" and jax.default_backend() == "tpu"):
+            out = kops.gqa_decode_attention(q1, k_hm, v_hm, kv_len,
+                                            mode=use_pallas)
+        else:
+            from ..kernels import ref as kref
+            out = kref.gqa_decode(q1, k_hm, v_hm, kv_len)
+    out = out.reshape(B, 1, cfg.q_dim)
     y = jnp.einsum("bse,ed->bsd", out, p["wo"]["w"])
     return y, (k_cache, v_cache)
 
@@ -358,6 +358,11 @@ def ffn_init(key, cfg: ModelConfig, d_ff: Optional[int] = None) -> Params:
 
 
 def ffn_apply(p, x, activation: str = "swiglu"):
+    with jax.named_scope("mlp"):
+        return _ffn(p, x, activation)
+
+
+def _ffn(p, x, activation: str):
     h = jnp.einsum("bsd,df->bsf", x, p["wi"]["w"])
     if activation == "swiglu":
         g = jnp.einsum("bsd,df->bsf", x, p["wg"]["w"])

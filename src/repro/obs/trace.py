@@ -37,9 +37,12 @@ __all__ = ["Phase", "SpanEvent", "Span", "Tracer", "NoopTracer",
 #: outside this set — the docs and the Chrome-trace colouring key off it.
 PHASE_NAMES = ("upload", "queue-wait", "prefill", "kv-transfer",
                "queue-wait-decode", "decode", "download", "serve")
-EVENT_NAMES = ("submit", "route-decision", "dispatch", "hedge", "cancel",
-               "failure", "complete", "reroute", "handoff-start", "retire",
-               "cohort-dispatch", "retry", "timeout", "shed")
+#: A span's start is its submit and its end its retire, so neither is an
+#: event; cohort dispatches are counted by the metrics registry
+#: (``cohort_participants``) and timed by the timeline.
+EVENT_NAMES = ("route-decision", "dispatch", "hedge", "cancel", "failure",
+               "complete", "reroute", "handoff-start", "retry", "timeout",
+               "shed")
 
 
 class Phase(NamedTuple):

@@ -46,6 +46,7 @@ import numpy as np
 from ..kernels import ops as kops
 from ..models import lm
 from ..models.config import ModelConfig
+from ..obs.timeline import NOOP_TIMELINE
 from .fleet import ChunkWork, FleetMemberStore, decode_chunk_body
 from .kvcache import CacheStats, PagedKVStore
 
@@ -65,16 +66,18 @@ def _decode_one(params, cfg: ModelConfig, tok, cache):
 
 @functools.partial(jax.jit, static_argnames=("cfg", "max_seq"))
 def _prefill_bucketed(params, cfg: ModelConfig, tokens, length, max_seq):
-    return lm.prefill(params, cfg, {"tokens": tokens}, max_seq=max_seq,
-                      length=length)
+    with jax.named_scope("prefill"):
+        return lm.prefill(params, cfg, {"tokens": tokens}, max_seq=max_seq,
+                          length=length)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "max_seq"))
 def _prefill_extend_bucketed(params, cfg: ModelConfig, tokens, length,
                              prefix, prefix_len, max_seq):
-    return lm.prefill_extend(params, cfg, {"tokens": tokens}, prefix,
-                             max_seq=max_seq, prefix_len=prefix_len,
-                             length=length)
+    with jax.named_scope("prefill"):
+        return lm.prefill_extend(params, cfg, {"tokens": tokens}, prefix,
+                                 max_seq=max_seq, prefix_len=prefix_len,
+                                 length=length)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "n", "eos"))
@@ -116,6 +119,7 @@ class _Slot:
     generated: List[int] = dataclasses.field(default_factory=list)
     budget: int = 0
     submit_step: int = 0       # engine step at submit()
+    admit_step: int = 0        # engine step when the slot was granted
     first_token_step: int = 0  # engine step when prefill emitted token 0
     block_ids: List[int] = dataclasses.field(default_factory=list)
     prompt_tokens: int = 0     # prompt length at admission
@@ -133,10 +137,14 @@ class _LocalStore:
 
 
 class LLMEngine:
-    def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig):
+    def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig,
+                 timeline=NOOP_TIMELINE):
+        """``timeline``: a ``repro.obs.Timeline`` for the admission spans
+        (``engine.admit`` and its parts); the shared no-op by default."""
         self.cfg = cfg
         self.params = params
         self.ecfg = ecfg
+        self.timeline = timeline
         # decode attention tiles the max_seq cache axis: a length it cannot
         # tile fails here, not mid-serve where it would pass for a node fault
         if any(m.startswith("attn") for m, _ in cfg.pattern):
@@ -510,6 +518,10 @@ class LLMEngine:
             "submit_step": s.submit_step,
             "first_token_step": s.first_token_step,
             "finish_step": finish_step,
+            # admission wait: steps queued before a slot was granted. The
+            # prefill emits token 0 at admission, so today it equals
+            # ttft_steps; the two differ once admission stops emitting
+            "queue_steps": s.admit_step - s.submit_step,
             "ttft_steps": s.first_token_step - s.submit_step,
             "tpot_steps": ((finish_step - s.first_token_step) / n_decode
                            if n_decode else 0.0),
@@ -548,9 +560,43 @@ class LLMEngine:
 
     def _prefill_into(self, slot: int, request_id: int, tokens: np.ndarray,
                       budget: int, extra: dict, submit_step: int = 0):
+        """Admit one queued request into free ``slot``: prefill, splice its
+        cache into the slot, read its first token. The ``engine.admit``
+        span, with ``admit.prefill``, ``admit.splice`` and
+        ``admit.first_token`` (the device sync that yields token 0)."""
+        L = len(tokens)
+        assert L + budget <= self.ecfg.max_seq, \
+            "request exceeds engine max_seq"
+        tl = self.timeline
+        with tl.span("engine.admit", request_id):
+            with tl.span("admit.prefill"):
+                logits, cache1, block_ids, prefix_len = self._prefill(
+                    tokens, extra)
+            with tl.span("admit.splice"):
+                self._splice(slot, cache1, L)
+            with tl.span("admit.first_token"):
+                first = int(jnp.argmax(logits[0]))
+            s = self.slots[slot]
+            s.request_id = request_id
+            s.generated = [first]
+            s.budget = budget - 1
+            s.submit_step = submit_step
+            s.admit_step = self._steps
+            s.first_token_step = self._steps
+            s.block_ids = block_ids
+            s.prompt_tokens = L
+            s.cached_tokens = prefix_len
+            self._next_token = self._next_token.at[slot, 0].set(first)
+            if s.budget <= 0:
+                self.results[request_id] = self._result(s, self._steps)
+                self._release_slot(slot)
+
+    def _prefill(self, tokens: np.ndarray, extra: dict):
+        """The prompt's prefill, reusing and extending the paged prefix
+        cache where there is one. Returns ``(logits, single-request cache,
+        block ids pinned, cached prefix length)``."""
         e = self.ecfg
         L = len(tokens)
-        assert L + budget <= e.max_seq, "request exceeds engine max_seq"
         matched: List[int] = []
         if self.kv is not None:
             matched = self.kv.cache.match(tokens)
@@ -626,7 +672,10 @@ class LLMEngine:
                 n_tok = (len(cached) + len(new_ids)) * self.kv.block_size
                 self.kv.cache.commit(tokens[:n_tok], cached + new_ids)
             block_ids = matched + new_ids
-        # splice single-request cache into batch cache at `slot`
+        return logits, cache1, block_ids, prefix_len
+
+    def _splice(self, slot: int, cache1, length: int) -> None:
+        """Write a single-request cache into the batch cache at ``slot``."""
         def splice(full, one):
             if full.ndim < 2:
                 return full
@@ -635,19 +684,5 @@ class LLMEngine:
         self.cache = self.cache._replace(
             layer=jax.tree.map(splice, self.cache.layer, cache1.layer),
             cross=jax.tree.map(splice, self.cache.cross, cache1.cross),
-            kv_len=self.cache.kv_len.at[slot].set(L),
+            kv_len=self.cache.kv_len.at[slot].set(length),
         )
-        first = int(jnp.argmax(logits[0]))
-        s = self.slots[slot]
-        s.request_id = request_id
-        s.generated = [first]
-        s.budget = budget - 1
-        s.submit_step = submit_step
-        s.first_token_step = self._steps
-        s.block_ids = block_ids
-        s.prompt_tokens = L
-        s.cached_tokens = prefix_len
-        self._next_token = self._next_token.at[slot, 0].set(first)
-        if s.budget <= 0:
-            self.results[request_id] = self._result(s, self._steps)
-            self._release_slot(slot)

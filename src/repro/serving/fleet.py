@@ -49,6 +49,7 @@ import numpy as np
 
 from ..models import lm
 from ..models.config import ModelConfig
+from ..obs.timeline import NOOP_TIMELINE
 from .kvcache import FleetKVPools
 
 
@@ -75,16 +76,18 @@ def decode_chunk_body(params, cfg: ModelConfig, tok, cache, budget, alive,
 
     def body(carry, _):
         tok, cache, budget, alive = carry
-        logits, cache = lm.decode_step(params, cfg, tok, cache)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        emit = alive
-        budget = budget - alive.astype(jnp.int32)
-        retire = alive & ((budget <= 0) | (nxt == eos))
-        alive = alive & ~retire
-        cache = cache._replace(kv_len=jnp.where(retire, 0, cache.kv_len))
-        tok = jnp.where(retire, 0, nxt)[:, None]
-        out = jnp.stack([nxt, emit.astype(jnp.int32),
-                         retire.astype(jnp.int32)])
+        with jax.named_scope("decode.step"):
+            logits, cache = lm.decode_step(params, cfg, tok, cache)
+        with jax.named_scope("decode.sample"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            emit = alive
+            budget = budget - alive.astype(jnp.int32)
+            retire = alive & ((budget <= 0) | (nxt == eos))
+            alive = alive & ~retire
+            cache = cache._replace(kv_len=jnp.where(retire, 0, cache.kv_len))
+            tok = jnp.where(retire, 0, nxt)[:, None]
+            out = jnp.stack([nxt, emit.astype(jnp.int32),
+                             retire.astype(jnp.int32)])
         return (tok, cache, budget, alive), out
 
     (tok, cache, budget, alive), outs = jax.lax.scan(
@@ -107,7 +110,8 @@ def _cohort_decode_chunk(params, cfg: ModelConfig, state: FleetState,
     only participants advance. Keyed on the shared static ``(cfg, n, eos)``
     — every cohort with the same model identity, member count and bucket
     size reuses one executable."""
-    sub = jax.tree.map(lambda a: a[idx], state)
+    with jax.named_scope("cohort.gather"):
+        sub = jax.tree.map(lambda a: a[idx], state)
     tok, cache, outs = jax.vmap(
         lambda t, c, b, a: decode_chunk_body(params, cfg, t, c, b, a, n, eos),
         in_axes=(0, 0, 0, 0))(sub.next_token, sub.cache, budget, alive)
@@ -116,9 +120,10 @@ def _cohort_decode_chunk(params, cfg: ModelConfig, state: FleetState,
         mask = valid.reshape(valid.shape + (1,) * (new.ndim - 1))
         return full.at[idx].set(jnp.where(mask, new, old))
 
-    new_state = FleetState(
-        cache=jax.tree.map(merge, state.cache, cache, sub.cache),
-        next_token=merge(state.next_token, tok, sub.next_token))
+    with jax.named_scope("cohort.merge"):
+        new_state = FleetState(
+            cache=jax.tree.map(merge, state.cache, cache, sub.cache),
+            next_token=merge(state.next_token, tok, sub.next_token))
     return new_state, outs
 
 
@@ -162,7 +167,9 @@ class FleetMemberStore:
     def _fresh(self) -> FleetState:
         c = self._cohort
         if self._local is None or self._epoch != c.epoch:
-            self._local = _member_gather(c.state, jnp.int32(self._member))
+            with c.timeline.span("fleet.gather"):
+                self._local = _member_gather(c.state,
+                                             jnp.int32(self._member))
             self._epoch = c.epoch
         return self._local
 
@@ -231,9 +238,11 @@ class Cohort:
     Adoption re-homes each engine's decode cache, next-token array and (when
     paged prefix reuse is on) K/V pools into stacked arrays with a leading
     member axis; the engines keep operating on views (``FleetMemberStore``).
-    ``dispatch`` advances every participating member in one jitted call."""
+    ``dispatch`` advances every participating member in one jitted call.
+    ``timeline`` (a ``repro.obs.Timeline``) gets the dispatch's spans and
+    row counters; the shared no-op by default."""
 
-    def __init__(self, engines: Sequence):
+    def __init__(self, engines: Sequence, timeline=NOOP_TIMELINE):
         assert engines, "a cohort needs at least one engine"
         e0 = engines[0]
         self.cfg = e0.cfg
@@ -246,6 +255,7 @@ class Cohort:
                 "cohort members must share one params pytree"
             assert e.fleet_ok, "engine pattern is not fleet-vectorizable"
         self.members = list(engines)
+        self.timeline = timeline
         M = len(self.members)
         stack = lambda *xs: jnp.stack(xs)
         self.state = FleetState(
@@ -288,7 +298,11 @@ class Cohort:
         not O(members). Returns per-member ``ChunkWork`` for the host commit
         plus vectorized emit/retire counts straight off the stacked
         (bucket, n, 3, B) output — the single transfer for the whole
-        cohort."""
+        cohort. With a timeline the dispatch is the ``cohort.dispatch`` span,
+        with ``cohort.flush``, ``cohort.launch`` (the jitted call) and
+        ``cohort.fetch`` (the wait for its outputs) inside, and it counts
+        ``cohort.rows_decoded`` (bucket × slots × iterations run) and
+        ``cohort.tokens_emitted``."""
         n = max(int(n), 1)
         work_slots: Dict[int, List[int]] = {}
         for m in eligible:
@@ -303,7 +317,15 @@ class Cohort:
         zero = np.zeros(M, np.int64)
         if not work_slots:
             return CohortDispatch({}, zero, zero)
-        self._flush()
+        with self.timeline.span("cohort.dispatch"):
+            return self._launch(n, work_slots)
+
+    def _launch(self, n: int, work_slots: Dict[int, List[int]]
+                ) -> CohortDispatch:
+        tl = self.timeline
+        with tl.span("cohort.flush"):
+            self._flush()
+        M = len(self.members)
         B = self.ecfg.max_slots
         parts = sorted(work_slots)
         k = len(parts)
@@ -322,10 +344,11 @@ class Cohort:
                 alive[r, i] = True
             n_eff[m] = min(n, int(budgets[r, alive[r]].max()))
         n_f = max(n_eff.values())
-        self.state, outs = _cohort_decode_chunk(
-            self.params, self.cfg, self.state, jnp.asarray(budgets),
-            jnp.asarray(alive), jnp.asarray(idx), jnp.asarray(valid), n_f,
-            self.ecfg.eos_token)
+        with tl.span("cohort.launch"):
+            self.state, outs = _cohort_decode_chunk(
+                self.params, self.cfg, self.state, jnp.asarray(budgets),
+                jnp.asarray(alive), jnp.asarray(idx), jnp.asarray(valid),
+                n_f, self.ecfg.eos_token)
         self.epoch += 1
         # non-participants' stacked rows are untouched: their (flushed)
         # local views stay valid across the epoch bump, so an idle member
@@ -336,7 +359,8 @@ class Cohort:
             if m not in changed and st._local is not None \
                     and st._epoch == self.epoch - 1:
                 st._epoch = self.epoch
-        outs_np = np.asarray(outs)        # ONE transfer for the whole cohort
+        with tl.span("cohort.fetch"):
+            outs_np = np.asarray(outs)    # ONE transfer for the whole cohort
         self.host_syncs += 1
         self.counters.dispatches += 1
         # fleet counters straight from the stacked emit/retire masks: rows
@@ -348,15 +372,19 @@ class Cohort:
         retired[parts] = outs_np[:k, :, 2, :].sum(axis=(1, 2))
         self.counters.emitted += emitted
         self.counters.retired += retired
+        if tl.enabled:
+            tl.count("cohort.rows_decoded", k_b * B * n_f)
+            tl.count("cohort.tokens_emitted", int(emitted.sum()))
         work = {m: ChunkWork(outs=outs_np[r], n_eff=n_eff[m],
                              active=tuple(work_slots[m]))
                 for r, m in enumerate(parts)}
         return CohortDispatch(work, emitted, retired)
 
 
-def build_cohorts(engines: Dict[int, object]):
+def build_cohorts(engines: Dict[int, object], timeline=NOOP_TIMELINE):
     """Group engines into cohorts by shared (ModelConfig, EngineConfig,
     params-identity); non-vectorizable engines (MoE patterns) stay loose.
+    Every cohort records into ``timeline``.
 
     Returns ``(cohorts, cohort_pairs, pair_to_cohort)`` where
     ``cohort_pairs[c]`` lists the pair ids of cohort ``c`` in pair order and
@@ -373,7 +401,7 @@ def build_cohorts(engines: Dict[int, object]):
     pair_to_cohort: Dict[int, tuple] = {}
     for pairs in groups.values():
         c = len(cohorts)
-        cohorts.append(Cohort([engines[p] for p in pairs]))
+        cohorts.append(Cohort([engines[p] for p in pairs], timeline))
         cohort_pairs.append(pairs)
         for m, p in enumerate(pairs):
             pair_to_cohort[p] = (c, m)

@@ -176,10 +176,12 @@ class ClusterServer:
         ONE jitted dispatch per tick; ``False`` keeps the per-engine Python
         loop (byte-identical results, O(#engines) dispatches).
         obs: optional ``repro.obs.Obs`` telemetry bundle — lifecycle spans
-        on the scheduler-tick clock, the shared metrics registry, and the
-        router decision audit. Defaults to ``Obs.noop()``: no span/audit
-        recording, but the metrics registry (always owned by the monitor)
-        still feeds ``stats()['percentiles']``.
+        on the scheduler-tick clock, the shared metrics registry, the
+        router decision audit, and the wall-clock layer timeline (handed to
+        the router, every engine and every cohort). Defaults to
+        ``Obs.noop()``: no span/audit/timeline recording, but the metrics
+        registry (always owned by the monitor) still feeds
+        ``stats()['percentiles']``.
         faults: optional ``repro.faults.FaultSchedule`` replayed against the
         runtime with times in **scheduler ticks** — crash windows fail/recover
         nodes, stragglers slow their decode rate, link flaps stretch KV
@@ -192,6 +194,7 @@ class ClusterServer:
         self.cluster = cluster
         self.obs = Obs.noop() if obs is None else obs
         self.tracer = self.obs.tracer
+        self.timeline = self.obs.timeline
         if faults is not None and resilience is None:
             resilience = ResilienceConfig()
         self.resilience = resilience
@@ -207,6 +210,7 @@ class ClusterServer:
         self.metrics = self.monitor.metrics  # always a live registry
         rkw = dict(router_kwargs or {})
         rkw.setdefault("audit", self.obs.audit)
+        rkw.setdefault("timeline", self.timeline)
         self.router = RequestRouter(cluster, thresholds, monitor=self.monitor,
                                     **rkw)
         self.engines: Dict[int, LLMEngine] = {}
@@ -214,7 +218,8 @@ class ClusterServer:
         for p, (j, k) in enumerate(cluster.pairs()):
             name = cluster.models[k].name
             mcfg, params = model_builders[name]
-            self.engines[p] = LLMEngine(mcfg, params, engine_cfg)
+            self.engines[p] = LLMEngine(mcfg, params, engine_cfg,
+                                        timeline=self.timeline)
             self.pair_model_cfg[p] = mcfg
         self.fleet = fleet
         self._cohorts: List[Cohort] = []
@@ -223,7 +228,7 @@ class ClusterServer:
         self._cohort_nodes: List[np.ndarray] = []
         if fleet:
             self._cohorts, self._cohort_pairs, self._pair_cohort = \
-                build_cohorts(self.engines)
+                build_cohorts(self.engines, timeline=self.timeline)
             pair_node = np.asarray(self.router.arrays.pair_node)
             self._cohort_nodes = [
                 np.asarray([pair_node[p] for p in pairs], np.int64)
@@ -495,6 +500,11 @@ class ClusterServer:
 
     # -- public ------------------------------------------------------------------
     def submit(self, sreq: ServeRequest):
+        # the timeline's server.submit span starts the request's queue wait
+        with self.timeline.span("server.submit", sreq.request_id):
+            self._submit(sreq)
+
+    def _submit(self, sreq: ServeRequest):
         # the span opens once here; reroutes/hedges reuse the open span
         self.tracer.begin(sreq.request_id, self.ticks)
         rcfg = self.resilience
@@ -610,13 +620,46 @@ class ClusterServer:
         congested engine that fell back to one iteration must not age its
         requests by the whole chunk, or stragglers would hedge chunk-times
         early exactly where the cluster is already loaded); the scheduler
-        clock stays one tick per call."""
-        self.ticks += 1
-        pair_node = np.asarray(self.router.arrays.pair_node)
-        # fault schedule + monitor clock first: crash/recover transitions,
-        # heartbeats (minus schedule-lost ones), breaker cooldowns, and the
-        # straggler slow-credit mask all apply to THIS tick's work below
-        self._fault_tick()
+        clock stays one tick per call.
+
+        With a ``Timeline`` the call is the ``server.step`` span, with the
+        parts below as child spans, and the live slots' KV positions are
+        counted before anything moves."""
+        tl = self.timeline
+        with tl.span("server.step"):
+            if tl.enabled:
+                self._count_kv()
+            self.ticks += 1
+            pair_node = np.asarray(self.router.arrays.pair_node)
+            # fault schedule + monitor clock first: crash/recover
+            # transitions, heartbeats (minus schedule-lost ones), breaker
+            # cooldowns, and the straggler slow-credit mask all apply to
+            # THIS tick's work below
+            self._fault_tick()
+            with tl.span("step.deliver"):
+                self._deliver_transfers(pair_node)
+            with tl.span("step.retry"):
+                self._drain_retries()
+            chunk_work = self._dispatch_cohorts(chunk, pair_node)
+            with tl.span("step.commit"):
+                advanced = self._commit_engines(chunk, chunk_work,
+                                                pair_node)
+            with tl.span("step.age"):
+                self._age_flights(advanced, pair_node)
+
+    def _count_kv(self) -> None:
+        """KV positions the live slots hold (prompt plus generated) and
+        the positions the engines reserve, from slot bookkeeping alone."""
+        held = reserved = 0
+        for eng in self.engines.values():
+            reserved += len(eng.slots) * eng.ecfg.max_seq
+            for s in eng.slots:
+                if s.request_id is not None:
+                    held += s.prompt_tokens + len(s.generated)
+        self.timeline.count("kv.positions_held", held)
+        self.timeline.count("kv.positions_reserved", reserved)
+
+    def _deliver_transfers(self, pair_node: np.ndarray) -> None:
         # deliver due KV handoffs: drop the source's export pins, land the
         # payload in the decode engine's pool (a full pool degrades to a
         # plain re-prefill — outputs stay byte-identical either way) and
@@ -663,6 +706,8 @@ class ClusterServer:
                 timeout_ticks=self._timeout_ticks(tr.category),
                 est_ttft=tr.est_ttft, est_tpot=tr.est_tpot,
                 complexity=tr.complexity, prefill_node=node_p)
+
+    def _drain_retries(self) -> None:
         # drain due retries (transient bounces and timed-out requests) —
         # after fault transitions so they route against this tick's masks
         for rid in [r for r, (due, _, _) in self._retry_queue.items()
@@ -670,6 +715,9 @@ class ClusterServer:
             _, sreq, attempt = self._retry_queue.pop(rid)
             self.tracer.event(rid, "retry", self.ticks, attempt=attempt)
             self._route_dispatch(sreq, attempt=attempt)
+
+    def _dispatch_cohorts(self, chunk: int,
+                          pair_node: np.ndarray) -> Dict[int, object]:
         # phase A — fleet data plane: one stacked decode dispatch per cohort.
         # Members mid-admission (queued work at chunk > 1), empty, on a
         # crashed node, or on a straggler without slow-credit this tick are
@@ -697,6 +745,10 @@ class ClusterServer:
             self._cohort_part.add(ci, res.participants)
             for m, w in res.work.items():
                 chunk_work[pairs[m]] = w
+        return chunk_work
+
+    def _commit_engines(self, chunk: int, chunk_work: Dict[int, object],
+                        pair_node: np.ndarray) -> Dict[int, int]:
         # phase B — host control plane, in pair order
         advanced: Dict[int, int] = {}
         for pair, eng in self.engines.items():
@@ -740,7 +792,7 @@ class ClusterServer:
                               category=fl.category)
                     m.observe("tpot", float(res["tpot_steps"]), node=node,
                               category=fl.category)
-                    m.observe("queue_wait", float(res["ttft_steps"]),
+                    m.observe("queue_wait", float(res["queue_steps"]),
                               node=node, category=fl.category)
                     m.observe("cache_hit_frac", float(res["cached_frac"]),
                               node=node, category=fl.category)
@@ -780,6 +832,10 @@ class ClusterServer:
                         self.tracer.event(rid, "cancel", self.ticks,
                                           node=int(pair_node[loser]))
                     self.tracer.end(rid, self.ticks, "completed")
+        return advanced
+
+    def _age_flights(self, advanced: Dict[int, int],
+                     pair_node: np.ndarray) -> None:
         # straggler hedging + deadline timeouts: age each request by its own
         # engine's progress (min 1 keeps the chunk=1 semantics for idle,
         # crashed, or credit-starved engines — wall-tick aging is exactly

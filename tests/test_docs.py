@@ -111,6 +111,18 @@ def test_architecture_doc_has_observability_section():
         assert name in doc, f"observability docs miss vocabulary: {name}"
 
 
+def test_architecture_doc_names_timeline_vocabulary():
+    """Every timeline span, counter and device scope name is documented
+    in the observability section, in lockstep with the code."""
+    doc = (REPO / "docs" / "architecture.md").read_text()
+    from repro.obs.timeline import COUNTER_NAMES, SCOPE_NAMES, SPAN_NAMES
+    for name in SPAN_NAMES + COUNTER_NAMES + SCOPE_NAMES:
+        assert f"`{name}`" in doc, f"timeline docs miss: {name}"
+    for needle in ("NOOP_TIMELINE", "perf_counter_ns", "TraceAnnotation",
+                   "self_times", "gc.callbacks"):
+        assert needle in doc, f"timeline docs miss: {needle}"
+
+
 def test_readme_and_bench_readme_name_obs():
     readme = (REPO / "README.md").read_text()
     assert "src/repro/obs/" in readme and "obs_overhead.py" in readme

@@ -14,8 +14,12 @@ The load-bearing properties:
   cancel) mirror the ``ClusterMonitor`` counter calls one-for-one, so
   ``total_dispatched == completed + failed + cancelled`` is checkable from
   the span log alone;
-* **zero-overhead no-op** — ``Obs.noop()`` changes nothing observable.
+* **zero-overhead no-op** — ``Obs.noop()`` changes nothing observable;
+* **timeline** — wall-clock layer spans nest with parent ids, a request's
+  spans share its id, self times tile their parent, the ring is bounded,
+  and serving counters match the slot bookkeeping without changing a token.
 """
+import gc
 import json
 import math
 import warnings
@@ -27,8 +31,10 @@ from conftest import make_session_trace, shared_cluster
 from repro.cluster.monitor import ClusterMonitor
 from repro.cluster.simulator import ClusterSimulator
 from repro.core.policies import get_policy
-from repro.obs import (NOOP_TRACER, AuditLog, Histogram, MetricsRegistry,
-                       Obs, Tracer, chrome_trace, metrics_flat)
+from repro.obs import (NOOP_TIMELINE, NOOP_TRACER, AuditLog, Histogram,
+                       MetricsRegistry, Obs, SpanRecord, Timeline, Tracer,
+                       chrome_trace, metrics_flat)
+from repro.obs.timeline import self_times
 from repro.workload.trace import build_trace
 
 REL_TOL = 1e-5   # float32 table arithmetic: ~2.4e-6 max relative error
@@ -518,3 +524,204 @@ def test_serving_disagg_handoff_traced():
 def _paper_defaults():
     from repro.core.policy import PAPER_DEFAULTS
     return PAPER_DEFAULTS
+
+
+# ---------------------------------------------------------------------------
+# timeline: wall-clock layer spans and counters inside the serving path
+# ---------------------------------------------------------------------------
+def _named(tl):
+    """Closed spans by name, garbage collections left out."""
+    return [r for r in tl.spans() if r.name != "python.gc"]
+
+
+def test_timeline_spans_nest_with_parent_ids():
+    tl = Timeline()
+    with tl.span("server.step"):
+        with tl.span("step.commit"):
+            with tl.span("engine.admit", 7):
+                pass
+        with tl.span("step.age"):
+            pass
+    recs = _named(tl)
+    # a span closes after everything opened inside it
+    assert [r.name for r in recs] == ["engine.admit", "step.commit",
+                                      "step.age", "server.step"]
+    by = {r.name: r for r in recs}
+    assert by["server.step"].parent == -1
+    assert by["step.commit"].parent == by["server.step"].id
+    assert by["engine.admit"].parent == by["step.commit"].id
+    assert by["step.age"].parent == by["server.step"].id
+    for r in recs:
+        assert r.start_ns <= r.end_ns
+        if r.parent >= 0:
+            p = next(q for q in recs if q.id == r.parent)
+            assert p.start_ns <= r.start_ns and r.end_ns <= p.end_ns
+
+
+def test_timeline_request_spans_share_rid():
+    tl = Timeline()
+    with tl.span("server.submit", 5):
+        with tl.span("router.route"):
+            pass
+        with tl.span("engine.admit", 5):
+            with tl.span("admit.first_token"):
+                pass
+    with tl.span("server.step"):
+        with tl.span("engine.admit", 9):
+            with tl.span("admit.prefill"):
+                pass
+        with tl.span("step.age"):
+            pass
+    rids = [(r.name, r.rid) for r in _named(tl)]
+    assert rids == [("router.route", 5), ("admit.first_token", 5),
+                    ("engine.admit", 5), ("server.submit", 5),
+                    ("admit.prefill", 9), ("engine.admit", 9),
+                    ("step.age", -1), ("server.step", -1)]
+
+
+def test_timeline_self_time_arithmetic():
+    recs = [SpanRecord(2, "engine.admit", 25, 45, 1, 3),
+            SpanRecord(1, "step.commit", 20, 50, 0, -1),
+            SpanRecord(3, "step.age", 60, 70, 0, -1),
+            SpanRecord(0, "server.step", 0, 100, -1, -1)]
+    want = {0: 100 - 30 - 10, 1: 30 - 20, 2: 20, 3: 10}
+    assert self_times(recs) == want
+    # the snapshot's plain lists read the same
+    assert self_times([list(r) for r in recs]) == want
+    assert sum(want.values()) == 100   # self times tile the top span
+
+
+def test_timeline_ring_bounds_counts_drops_and_counters():
+    tl = Timeline(capacity=4)
+    gc.disable()          # no python.gc span may take a ring slot here
+    try:
+        for i in range(7):
+            with tl.span("server.step", i):
+                pass
+        tl.count("kv.positions_held", 3)
+        tl.count("kv.positions_held", 4)
+    finally:
+        gc.enable()
+    assert len(tl) == 4 and tl.dropped == 3
+    assert [r.rid for r in tl.spans()] == [3, 4, 5, 6]
+    snap = tl.snapshot()
+    assert snap["dropped"] == 3 and snap["capacity"] == 4
+    assert snap["counters"] == {"kv.positions_held": 7}
+    assert [s[5] for s in snap["spans"]] == [3, 4, 5, 6]
+    tl.clear()
+    assert len(tl) == 0 and tl.dropped == 0 and tl.counters == {}
+
+
+def test_timeline_records_garbage_collections_under_open_span():
+    tl = Timeline()
+    with tl.span("server.step"):
+        gc.collect()
+    recs = tl.spans()
+    step = next(r for r in recs if r.name == "server.step")
+    gcs = [r for r in recs if r.name == "python.gc"]
+    assert gcs and all(r.parent == step.id for r in gcs)
+    assert all(step.start_ns <= r.start_ns <= r.end_ns <= step.end_ns
+               for r in gcs)
+
+
+def test_noop_timeline_allocates_no_record(monkeypatch):
+    import jax
+
+    from repro.obs import timeline as tlmod
+
+    def boom(*a, **k):
+        raise AssertionError("the no-op timeline read a clock or annotated")
+
+    monkeypatch.setattr(tlmod.time, "perf_counter_ns", boom)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", boom)
+    # one shared null span: a span site allocates nothing
+    assert NOOP_TIMELINE.span("server.step") is NOOP_TIMELINE.span("x", 3)
+    with NOOP_TIMELINE.span("server.step"):
+        with NOOP_TIMELINE.span("engine.admit", 1):
+            NOOP_TIMELINE.count("kv.positions_held", 5)
+    assert not NOOP_TIMELINE.enabled
+    assert len(NOOP_TIMELINE) == 0 and NOOP_TIMELINE.spans() == []
+    assert NOOP_TIMELINE.counters == {} and NOOP_TIMELINE.dropped == 0
+    assert Obs().timeline is NOOP_TIMELINE
+    assert Obs.noop().timeline is NOOP_TIMELINE
+
+
+@pytest.mark.parametrize("fleet", [True, False], ids=["fleet", "per_engine"])
+def test_serving_timeline_counters_match_slots(serve_parts, fleet):
+    """With the timeline on, the KV counters equal the slot bookkeeping
+    recomputed before every step, every request's spans carry its id, and
+    the served tokens equal a timeline-off run's."""
+    from repro.serving import ClusterServer, EngineConfig, ServeRequest
+    cluster, builders, trace = serve_parts
+    ecfg = EngineConfig(max_slots=2, max_seq=48, max_new_tokens=4)
+
+    def serve(timeline):
+        srv = ClusterServer(cluster, builders, _paper_defaults(), ecfg,
+                            fleet=fleet,
+                            obs=Obs(tracer=NOOP_TRACER, metrics=None,
+                                    audit=None, timeline=timeline))
+        for i, r in enumerate(trace.requests[:8]):
+            srv.submit(ServeRequest(request_id=i, req=r, max_new_tokens=4))
+        held = reserved = 0
+        while srv.inflight:
+            for eng in srv.engines.values():
+                reserved += len(eng.slots) * eng.ecfg.max_seq
+                held += sum(s.prompt_tokens + len(s.generated)
+                            for s in eng.slots if s.request_id is not None)
+            srv.step()
+        return srv, held, reserved
+
+    off, _, _ = serve(NOOP_TIMELINE)
+    tl = Timeline()
+    on, held, reserved = serve(tl)
+    assert sorted(on.done) == list(range(8))
+    assert ({r: d["tokens"] for r, d in on.done.items()}
+            == {r: d["tokens"] for r, d in off.done.items()})
+    c = tl.counters
+    assert held > 0
+    assert c["kv.positions_held"] == held
+    assert c["kv.positions_reserved"] == reserved
+    assert tl.dropped == 0
+    recs = tl.spans()
+    names = {r.name for r in recs}
+    assert {"server.submit", "router.route", "server.step", "step.deliver",
+            "step.retry", "step.commit", "step.age", "engine.admit",
+            "admit.prefill", "admit.splice",
+            "admit.first_token"} <= names
+    admits = [r for r in recs if r.name == "engine.admit"]
+    assert sorted(r.rid for r in admits) == list(range(8))
+    by_id = {r.id: r for r in recs}
+    for r in recs:
+        if r.name.startswith("admit."):
+            assert by_id[r.parent].name == "engine.admit"
+            assert r.rid == by_id[r.parent].rid
+        if r.name == "router.route":
+            assert by_id[r.parent].name == "server.submit"
+    if fleet:
+        assert {"cohort.dispatch", "cohort.flush", "cohort.launch",
+                "cohort.fetch", "fleet.gather"} <= names
+        emitted = sum(int(co.counters.emitted.sum()) for co in on._cohorts)
+        assert c["cohort.tokens_emitted"] == emitted > 0
+        assert c["cohort.rows_decoded"] >= emitted
+    else:
+        assert "cohort.dispatch" not in names
+        assert "cohort.rows_decoded" not in c
+
+
+def test_serving_queue_wait_records_admission_wait(serve_parts):
+    """``queue_wait`` holds each request's steps queued before its slot
+    (``queue_steps``), not a copy of its TTFT."""
+    from repro.serving import ClusterServer, EngineConfig, ServeRequest
+    cluster, builders, trace = serve_parts
+    srv = ClusterServer(cluster, builders, _paper_defaults(),
+                        EngineConfig(max_slots=1, max_seq=48,
+                                     max_new_tokens=3))
+    for i, r in enumerate(trace.requests[:8]):
+        srv.submit(ServeRequest(request_id=i, req=r, max_new_tokens=3))
+    done = srv.run()
+    waits = [d["queue_steps"] for d in done.values()]
+    assert max(waits) > 0      # one slot per engine: some requests queued
+    for d in done.values():
+        assert d["queue_steps"] == d["first_token_step"] - d["submit_step"]
+    h = srv.metrics.hist("queue_wait")
+    assert h.n == len(done) and h.total == sum(waits)
