@@ -270,10 +270,14 @@ def attention_prefill_extend(p, cfg: ModelConfig, x, positions, prefix_kv,
     return y, (k, v)
 
 
-def attention_decode(p, cfg: ModelConfig, x, cache, pos,
+def attention_decode(p, cfg: ModelConfig, x, cache, pos, layer,
                      use_pallas: str = "auto"):
-    """One-token decode. x: (B, 1, d); cache: (k, v) each (B, Smax, Hkv, D);
-    pos: (B,) current lengths. Returns (y, new_cache).
+    """One-token decode. x: (B, 1, d); cache: (k, v) each stacked over
+    periods, (P, B, Smax, Hkv, D) or folded (P, B, Smax, Hkv·D)
+    (``lm.fold_kv``); pos: (B,) current lengths. The new token's K/V is
+    written at ``(layer, row, pos[row])`` of the stacked buffers, in place
+    where they are a loop carry, and the kernel reads that period's slice.
+    Returns (y, new_cache) in the form given.
 
     Sharding: the KV cache is head_dim-sharded over 'model' (Hkv rarely
     divides the axis), so q/k/v here are constrained to the SAME hd sharding
@@ -289,19 +293,24 @@ def attention_decode(p, cfg: ModelConfig, x, cache, pos,
     k_new = constrain(k_new, "dp", None, None, "model")
     v_new = constrain(v_new, "dp", None, None, "model")
     k_cache, v_cache = cache
-    # write at pos (per batch row)
+    S = k_cache.shape[2]
+    row = (B,) + k_cache.shape[3:]
+    # write at pos (per batch row); "clip" clamps a position past the buffer
+    # to its last row, as dynamic_update_slice does
     with jax.named_scope("attn.kv_write"):
-        k_cache = jax.vmap(
-            lambda c, kn, i: jax.lax.dynamic_update_slice_in_dim(c, kn, i, 0)
-        )(k_cache, k_new, pos)
-        v_cache = jax.vmap(
-            lambda c, vn, i: jax.lax.dynamic_update_slice_in_dim(c, vn, i, 0)
-        )(v_cache, v_new, pos)
+        rows = jnp.arange(B)
+        k_cache = k_cache.at[layer, rows, pos].set(
+            k_new[:, 0].reshape(row), mode="clip")
+        v_cache = v_cache.at[layer, rows, pos].set(
+            v_new[:, 0].reshape(row), mode="clip")
     kv_len = pos + 1
     # the kernels read the cache heads-major
     with jax.named_scope("attn.kv_layout"):
-        k_hm = jnp.transpose(k_cache, (0, 2, 1, 3))
-        v_hm = jnp.transpose(v_cache, (0, 2, 1, 3))
+        k_hm, v_hm = (
+            jnp.transpose(jax.lax.dynamic_index_in_dim(c, layer, 0, False)
+                          .reshape(B, S, cfg.n_kv_heads, cfg.hd),
+                          (0, 2, 1, 3))
+            for c in (k_cache, v_cache))
     q1 = q[:, 0].reshape(B, cfg.n_heads, cfg.hd)
     with jax.named_scope("attn.kernel"):
         if use_pallas in ("pallas", "interpret") or (

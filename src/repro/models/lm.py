@@ -49,6 +49,31 @@ class Cache(NamedTuple):
     pos: jax.Array                   # scalar int32 cursor
 
 
+#: Mixers whose decode state is a K/V cache written one position per step.
+_KV_MIXERS = ("attn", "attn_bidir", "attn_cross")
+
+
+def fold_kv(cfg: ModelConfig, cache: Cache) -> Cache:
+    """``cache`` with each attention K/V leaf folded from (..., S, Hkv, D)
+    to (..., S, Hkv·D). ``decode_step`` reads and writes either form. On a
+    TPU the folded form's default layout is the one the decode loop works
+    in, while (S, Hkv, D) with a head dim short of 128 lanes (stablelm's
+    80) defaults to sequence-minor and costs a whole-cache relayout where
+    a dispatch takes and returns it."""
+    fold = lambda a: a.reshape(a.shape[:-2] + (-1,))
+    return cache._replace(layer=tuple(
+        tuple(map(fold, c)) if mixer in _KV_MIXERS else c
+        for (mixer, _), c in zip(cfg.pattern, cache.layer)))
+
+
+def unfold_kv(cfg: ModelConfig, cache: Cache) -> Cache:
+    """Inverse of ``fold_kv``."""
+    unfold = lambda a: a.reshape(a.shape[:-1] + (cfg.n_kv_heads, cfg.hd))
+    return cache._replace(layer=tuple(
+        tuple(map(unfold, c)) if mixer in _KV_MIXERS else c
+        for (mixer, _), c in zip(cfg.pattern, cache.layer)))
+
+
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -534,15 +559,16 @@ def _rep(x, n):
 
 
 def _apply_block_decode(p, cfg: ModelConfig, mixer: str, ffn: str, x,
-                        cache_entry, cross_entry, kv_len, pos, use_pallas):
-    """x: (B, 1, d). Returns (x, new_cache_entry)."""
+                        cache_entry, cross_entry, kv_len, layer, use_pallas):
+    """x: (B, 1, d). ``cache_entry`` is, for a K/V mixer, the (k, v) of every
+    period, written at period ``layer``; for a recurrent mixer, this period's
+    state. Returns (x, new_cache_entry)."""
     h = L.apply_norm(cfg, p["norm1"], x)
     new_entry = cache_entry
-    if mixer in ("attn", "attn_bidir", "attn_cross"):
-        k, v = cache_entry
-        y, (k, v) = L.attention_decode(p["mixer"], cfg, h, (k, v), kv_len,
-                                       use_pallas=use_pallas)
-        new_entry = (k, v)
+    if mixer in _KV_MIXERS:
+        y, new_entry = L.attention_decode(p["mixer"], cfg, h, cache_entry,
+                                          kv_len, layer,
+                                          use_pallas=use_pallas)
         x = x + y
         if mixer == "attn_cross":
             h2 = L.apply_norm(cfg, p["norm1b"], x)
@@ -573,37 +599,51 @@ def _apply_block_decode(p, cfg: ModelConfig, mixer: str, ffn: str, x,
 
 def decode_step(params, cfg: ModelConfig, token, cache: Cache,
                 use_pallas="auto", unroll=False):
-    """token: (B, 1) int32. Returns (logits (B, vocab), new Cache)."""
-    B = token.shape[0]
-    x = L.embed(params["embed"], token)
+    """token: (B, 1) int32. Returns (logits (B, vocab), new Cache).
 
-    def period_body(x, sl):
-        stacked, layer_c, cross_c = sl
-        new_cs = []
+    The K/V of attention positions stay stacked over periods in the period
+    loop's carry: each period writes the new token's K/V at its own index,
+    in place, so the loop neither slices the whole cache in nor emits it
+    again. Recurrent states and read-only cross K/V are small and are
+    scanned per period."""
+    x = L.embed(params["embed"], token)
+    is_kv = tuple(mixer in _KV_MIXERS for mixer, _ in cfg.pattern)
+    kv = tuple(c if a else () for a, c in zip(is_kv, cache.layer))
+    state = tuple(() if a else c for a, c in zip(is_kv, cache.layer))
+
+    def period_body(carry, sl):
+        x, kv, layer = carry
+        stacked, state_c, cross_c = sl
+        kv, new_state = list(kv), []
         x = constrain(x, "dp", None, None)
         for i, (mixer, ffn) in enumerate(cfg.pattern):
             x, nc = _apply_block_decode(
-                stacked[i], cfg, mixer, ffn, x, layer_c[i], cross_c[i],
-                cache.kv_len, cache.pos, use_pallas)
-            new_cs.append(nc)
-        return x, tuple(new_cs)
+                stacked[i], cfg, mixer, ffn, x,
+                kv[i] if is_kv[i] else state_c[i], cross_c[i],
+                cache.kv_len, layer, use_pallas)
+            if is_kv[i]:
+                kv[i] = nc
+            new_state.append(() if is_kv[i] else nc)
+        return (x, tuple(kv), layer + 1), tuple(new_state)
 
+    xs = (params["blocks"], state, cache.cross)
     if unroll:
-        new_per_period = []
+        carry, per_period = (x, kv, 0), []
         for pi in range(cfg.n_periods):
-            sl = jax.tree.map(lambda a: a[pi],
-                              (params["blocks"], cache.layer, cache.cross))
-            x, ncs = period_body(x, sl)
-            new_per_period.append(ncs)
-        new_layer = jax.tree.map(lambda *xs: jnp.stack(xs), *new_per_period)
+            carry, ncs = period_body(carry,
+                                     jax.tree.map(lambda a: a[pi], xs))
+            per_period.append(ncs)
+        new_state = jax.tree.map(lambda *xs: jnp.stack(xs), *per_period)
     else:
-        x, new_layer = jax.lax.scan(
-            lambda c, sl: period_body(c, sl), x,
-            (params["blocks"], cache.layer, cache.cross))
+        carry, new_state = jax.lax.scan(period_body, (x, kv, jnp.int32(0)),
+                                        xs)
+    x, kv, _ = carry
     x = (L.apply_norm(cfg, params["final_norm"], x) if cfg.norm == "rmsnorm"
          else L.layer_norm(params["final_norm"], x, cfg.norm_eps))
     head = params.get("head", params["embed"])
     logits = L.unembed(head, x)[:, 0]
+    new_layer = tuple(k if a else st
+                      for a, k, st in zip(is_kv, kv, new_state))
     new_cache = cache._replace(layer=new_layer, kv_len=cache.kv_len + 1,
                                pos=cache.pos + 1)
     return logits, new_cache

@@ -48,7 +48,8 @@ SPAN_NAMES = (
     "python.gc")
 #: Named integer sums.
 COUNTER_NAMES = ("cohort.rows_decoded", "cohort.tokens_emitted",
-                 "kv.positions_held", "kv.positions_reserved")
+                 "cohort.members_gathered", "kv.positions_held",
+                 "kv.positions_reserved")
 #: ``jax.named_scope`` names on the device ops (op_name metadata).
 SCOPE_NAMES = ("cohort.gather", "cohort.merge", "decode.step",
                "decode.sample", "attn.kv_write", "attn.kv_layout",

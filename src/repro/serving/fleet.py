@@ -14,12 +14,15 @@ Split of responsibilities (the host/device contract):
 * **device data plane (here)** — ``FleetState`` (stacked ``lm.Cache`` +
   next-token array), ``decode_chunk_body`` (the un-jitted scan shared with
   ``engine._decode_chunk``), and the module-level ``_cohort_decode_chunk``
-  jit keyed on the shared static config. A dispatch gathers only the
+  jit keyed on the shared static config. A dispatch decodes the stacked
+  state in place (donated, attention K/V folded). When every member
+  participates it runs on the whole state; otherwise it gathers only the
   **participating** members — their rows are indexed out inside the jit at
-  a power-of-two-padded participant count (the PR 4 bucketing idiom, so the
-  drain tail of a replay costs O(participants), not O(members)) — runs the
-  vmapped chunk on that sub-fleet, and scatters the survivors back; a
-  skipped member's device state never advances.
+  a power-of-two-padded participant count below the member count (the
+  bucketing idiom of the prefills, so the drain tail of a replay costs
+  O(participants), not O(members)) — runs the vmapped chunk on that
+  sub-fleet, and scatters the participants' rows back; a skipped member's
+  device state never advances.
 * **host control plane (``engine.LLMEngine``)** — admission, continuous
   batching, prefix-cache matching and result accounting are unchanged; a
   fleet-adopted engine simply reads and writes its device state through a
@@ -55,9 +58,10 @@ from .kvcache import FleetKVPools
 
 class FleetState(NamedTuple):
     """Stacked device state for one cohort: every leaf carries a leading
-    member (node) axis."""
+    member (node) axis. The stacked cache holds its attention K/V folded
+    (``lm.fold_kv``); a member view unfolds its own slice."""
 
-    cache: object          # lm.Cache, leaves (M, ...)
+    cache: object          # lm.Cache, leaves (M, ...), K/V folded
     next_token: jnp.ndarray  # (M, B, 1)
 
 
@@ -95,52 +99,68 @@ def decode_chunk_body(params, cfg: ModelConfig, tok, cache, budget, alive,
     return tok, cache, outs
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "n", "eos"))
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _stack(cfg: ModelConfig, members: Sequence[FleetState]) -> FleetState:
+    """The members' device states stacked on a leading member axis, K/V
+    folded (``lm.fold_kv``)."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *[
+        m._replace(cache=lm.fold_kv(cfg, m.cache)) for m in members])
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "n", "eos"),
+                   donate_argnames=("state",))
 def _cohort_decode_chunk(params, cfg: ModelConfig, state: FleetState,
                          budget, alive, idx, valid, n: int, eos: int):
-    """One dispatch for a cohort's participating members: gather the rows
-    named by ``idx`` out of the stacked state, ``decode_chunk_body`` vmapped
-    over that sub-fleet, scatter the valid rows back.
+    """One dispatch for a cohort's participating members,
+    ``decode_chunk_body`` vmapped over them. ``state`` is donated: the
+    returned state reuses its buffers, written in place.
 
-    ``budget``/``alive`` are (K, B) for the K-row participant bucket;
-    ``idx`` is (K,) **unique** member rows (participants first, padded to a
-    power of two with distinct idle members so the scatter stays
-    deterministic) and ``valid`` the (K,) mask of real participants —
-    padding rows write their gathered pre-dispatch values straight back, so
-    only participants advance. Keyed on the shared static ``(cfg, n, eos)``
-    — every cohort with the same model identity, member count and bucket
-    size reuses one executable."""
+    ``budget``/``alive`` are (K, B) for the K-row participant bucket. A
+    bucket of all M members (K == M, a static shape) runs on the whole
+    stacked state, with ``idx`` the identity: nothing is gathered or merged.
+    A smaller bucket gathers the rows named by ``idx`` — (K,) **unique**
+    member rows, participants first, padded to a power of two with distinct
+    idle members — and writes back only the rows that ``valid`` marks as
+    participants, in place; a padding row's write is dropped, so an idle
+    member keeps its state bit for bit. Keyed on the shared static
+    ``(cfg, n, eos)`` — every cohort with the same model identity, member
+    count and bucket size reuses one executable."""
+    run = jax.vmap(
+        lambda t, c, b, a: decode_chunk_body(params, cfg, t, c, b, a, n, eos))
+    M = state.next_token.shape[0]
+    if budget.shape[0] == M:
+        tok, cache, outs = run(state.next_token, state.cache, budget, alive)
+        return FleetState(cache=cache, next_token=tok), outs
     with jax.named_scope("cohort.gather"):
         sub = jax.tree.map(lambda a: a[idx], state)
-    tok, cache, outs = jax.vmap(
-        lambda t, c, b, a: decode_chunk_body(params, cfg, t, c, b, a, n, eos),
-        in_axes=(0, 0, 0, 0))(sub.next_token, sub.cache, budget, alive)
-
-    def merge(full, new, old):
-        mask = valid.reshape(valid.shape + (1,) * (new.ndim - 1))
-        return full.at[idx].set(jnp.where(mask, new, old))
-
+    tok, cache, outs = run(sub.next_token, sub.cache, budget, alive)
     with jax.named_scope("cohort.merge"):
-        new_state = FleetState(
-            cache=jax.tree.map(merge, state.cache, cache, sub.cache),
-            next_token=merge(state.next_token, tok, sub.next_token))
+        rows = jnp.where(valid, idx, M)     # M is past the end: dropped
+        new_state = jax.tree.map(
+            lambda full, new: full.at[rows].set(new, mode="drop"),
+            state, FleetState(cache=cache, next_token=tok))
     return new_state, outs
 
 
-@jax.jit
-def _member_gather(state: FleetState, m):
-    """One member's slice of the stacked state — ONE jitted call for the
-    whole pytree (an eager per-leaf gather costs ~a millisecond of Python
-    per read on the admission hot path)."""
-    return jax.tree.map(
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _member_gather(state: FleetState, m, cfg: ModelConfig):
+    """One member's slice of the stacked state, K/V unfolded — ONE jitted
+    call for the whole pytree (an eager per-leaf gather costs ~a millisecond
+    of Python per read on the admission hot path)."""
+    one = jax.tree.map(
         lambda a: jax.lax.dynamic_index_in_dim(a, m, 0, keepdims=False),
         state)
+    return one._replace(cache=lm.unfold_kv(cfg, one.cache))
 
 
-@jax.jit
-def _member_scatter(state: FleetState, local: FleetState, m):
-    """Write one member's slice back into the stacked state (one jitted
-    call; traced member index, so every member shares one executable)."""
+@functools.partial(jax.jit, static_argnames=("cfg",),
+                   donate_argnames=("state",))
+def _member_scatter(state: FleetState, local: FleetState, m,
+                    cfg: ModelConfig):
+    """Write one member's slice back into the stacked state, in place: the
+    state is donated (one jitted call; traced member index, so every member
+    shares one executable)."""
+    local = local._replace(cache=lm.fold_kv(cfg, local.cache))
     return jax.tree.map(
         lambda full, one: jax.lax.dynamic_update_index_in_dim(
             full, one, m, 0), state, local)
@@ -169,7 +189,7 @@ class FleetMemberStore:
         if self._local is None or self._epoch != c.epoch:
             with c.timeline.span("fleet.gather"):
                 self._local = _member_gather(c.state,
-                                             jnp.int32(self._member))
+                                             jnp.int32(self._member), c.cfg)
             self._epoch = c.epoch
         return self._local
 
@@ -257,10 +277,9 @@ class Cohort:
         self.members = list(engines)
         self.timeline = timeline
         M = len(self.members)
-        stack = lambda *xs: jnp.stack(xs)
-        self.state = FleetState(
-            cache=jax.tree.map(stack, *[e.cache for e in self.members]),
-            next_token=jnp.stack([e._next_token for e in self.members]))
+        self.state = _stack(self.cfg, [
+            FleetState(cache=e.cache, next_token=e._next_token)
+            for e in self.members])
         self.kv_pools: Optional[FleetKVPools] = None
         if self.ecfg.prefix_cache and all(e.kv is not None
                                           for e in self.members):
@@ -282,7 +301,7 @@ class Cohort:
         for m in sorted(self._dirty):
             self.state = _member_scatter(self.state,
                                          self.members[m]._store._local,
-                                         jnp.int32(m))
+                                         jnp.int32(m), self.cfg)
         self._dirty.clear()
 
     def dispatch(self, n: int, eligible: Sequence[int]) -> CohortDispatch:
@@ -329,7 +348,9 @@ class Cohort:
         B = self.ecfg.max_slots
         parts = sorted(work_slots)
         k = len(parts)
-        k_b = min(1 << (k - 1).bit_length(), M)   # pow2 bucket, capped at M
+        # pow2 bucket; one of M rows only when all M ride, so that bucket
+        # decodes the whole state in place and a partial one pads below M
+        k_b = M if k == M else min(1 << (k - 1).bit_length(), M - 1)
         pads = [m for m in range(M) if m not in work_slots][:k_b - k]
         idx = np.asarray(parts + pads, np.int32)
         valid = np.zeros(k_b, bool)
@@ -352,12 +373,14 @@ class Cohort:
         self.epoch += 1
         # non-participants' stacked rows are untouched: their (flushed)
         # local views stay valid across the epoch bump, so an idle member
-        # never re-gathers; participants re-gather lazily on next read
+        # never re-gathers; participants drop their stale copy (device
+        # memory) and re-gather lazily on next read
         changed = set(parts)
         for m, eng in enumerate(self.members):
             st = eng._store
-            if m not in changed and st._local is not None \
-                    and st._epoch == self.epoch - 1:
+            if m in changed:
+                st._local = None
+            elif st._local is not None and st._epoch == self.epoch - 1:
                 st._epoch = self.epoch
         with tl.span("cohort.fetch"):
             outs_np = np.asarray(outs)    # ONE transfer for the whole cohort
@@ -375,6 +398,7 @@ class Cohort:
         if tl.enabled:
             tl.count("cohort.rows_decoded", k_b * B * n_f)
             tl.count("cohort.tokens_emitted", int(emitted.sum()))
+            tl.count("cohort.members_gathered", 0 if k_b == M else k_b)
         work = {m: ChunkWork(outs=outs_np[r], n_eff=n_eff[m],
                              active=tuple(work_slots[m]))
                 for r, m in enumerate(parts)}

@@ -11,6 +11,8 @@ only ever touches the *discarded* copy (see docs/architecture.md).
 """
 import dataclasses
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,6 +20,7 @@ from repro.cluster.spec import disagg_testbed, fleet_testbed, paper_testbed
 from repro.core.policy import PAPER_DEFAULTS
 from repro.core.policies import runtime_policies
 from repro.launch.serve import build_models
+from repro.obs.timeline import Timeline
 from repro.serving import ClusterServer, EngineConfig, LLMEngine, ServeRequest
 from repro.serving import fleet as fleet_mod
 from repro.workload.trace import build_trace
@@ -270,3 +273,79 @@ def test_equal_cohorts_share_one_trace(tiny_model):
     c1.dispatch(2, [0, 1])
     assert fleet_mod._cohort_decode_chunk._cache_size() == after_first, \
         "equal-static cohorts must reuse one executable"
+
+
+# ---------------------------------------------------------------------------
+# in-place decode: donated state, dropped padding, gather counter
+# ---------------------------------------------------------------------------
+def _members(tiny_model, n, active, seed=0):
+    cfg, params = tiny_model
+    ecfg = EngineConfig(max_slots=2, max_seq=48, max_new_tokens=4)
+    rng = np.random.default_rng(seed)
+    engines = [LLMEngine(cfg, params, ecfg) for _ in range(n)]
+    for m in active:
+        engines[m].submit(m, rng.integers(0, cfg.vocab, size=6),
+                          max_new_tokens=4)
+    return engines
+
+
+def _member_np(cohort, m):
+    return [np.asarray(a) for a in jax.tree.leaves(
+        fleet_mod._member_gather(cohort.state, jnp.int32(m), cohort.cfg))]
+
+
+def test_dispatch_and_flush_donate_the_stacked_state(tiny_model):
+    """A dispatch and a flush each consume the stacked state they are
+    given: its cache leaves are deleted, the new state holds what the member
+    wrote, and the member's view still reads it."""
+    cfg, _ = tiny_model
+    engines = _members(tiny_model, 2, active=[0])
+    cohort = fleet_mod.Cohort(engines)
+    old = cohort.state
+    cohort.dispatch(1, [0, 1])
+    assert all(a.is_deleted() for a in jax.tree.leaves(old.cache))
+    engines[1].submit(7, np.arange(6) % cfg.vocab, max_new_tokens=4)
+    written = [np.asarray(a)
+               for a in jax.tree.leaves(engines[1]._store._local)]
+    assert 1 in cohort._dirty
+    old = cohort.state
+    cohort._flush()
+    assert all(a.is_deleted() for a in jax.tree.leaves(old.cache))
+    for a, b in zip(_member_np(cohort, 1), written):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(jax.tree.leaves(engines[1]._store._fresh()), written):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    assert int(engines[1].cache.kv_len[0]) == 6
+
+
+def test_padded_bucket_leaves_idle_members_bit_identical(tiny_model):
+    """Three of five members ride a bucket of four: the padding member and
+    the idle one keep every leaf of their state bit for bit, while each
+    participant advances one position."""
+    engines = _members(tiny_model, 5, active=range(5))
+    cohort = fleet_mod.Cohort(engines, timeline=Timeline())
+    before = {m: _member_np(cohort, m) for m in range(5)}
+    kv = {m: np.asarray(engines[m].cache.kv_len) for m in range(5)}
+    cohort.dispatch(1, [0, 2, 3])      # bucket of 4: member 1 pads
+    assert cohort.timeline.counters["cohort.members_gathered"] == 4
+    for m in (1, 4):
+        for a, b in zip(_member_np(cohort, m), before[m]):
+            np.testing.assert_array_equal(a, b)
+    for m in (0, 2, 3):
+        now = np.asarray(fleet_mod._member_gather(
+            cohort.state, jnp.int32(m), cohort.cfg).cache.kv_len)
+        np.testing.assert_array_equal(now, kv[m] + 1)
+
+
+def test_members_gathered_counts_only_gathered_dispatches(tiny_model):
+    """``cohort.members_gathered`` adds 0 for a dispatch of every member
+    (the whole state, in place) and the bucket size for a gathered one."""
+    engines = _members(tiny_model, 3, active=range(3))
+    tl = Timeline()
+    cohort = fleet_mod.Cohort(engines, timeline=tl)
+    cohort.dispatch(1, [0, 1, 2])
+    assert tl.counters["cohort.members_gathered"] == 0
+    cohort.dispatch(1, [2])
+    assert tl.counters["cohort.members_gathered"] == 1
+    cohort.dispatch(1, [0, 2])
+    assert tl.counters["cohort.members_gathered"] == 3

@@ -10,6 +10,7 @@ The topology is described inside a module fixture — never at import — so
 that only the worker that runs this file loads the TPU compiler library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,3 +105,58 @@ def test_dominance_compiles(one_chip, kernel, M):
           "counts": ops.dominance_counts}[kernel]
     F = _spec((200, M), jnp.float32, one_chip)    # pads to two 128-blocks
     _compile(lambda F: fn(F, mode="pallas"), F)
+
+
+def _entry_ops(hlo):
+    """``(opcode, shape, parameter number)`` of each instruction in the
+    entry computation of an optimized HLO module's text."""
+    entry = re.search(r"\nENTRY .*?\n}\n", hlo, re.S).group(0)
+    ops = re.findall(r"\n\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(([^)]*)\)", entry)
+    return [(op, shape, args) for shape, op, args in ops]
+
+
+@pytest.mark.parametrize("arch,n_layers,slots,max_seq,members,bucket", [
+    ("stablelm-3b", 2, 12, 512, 1, 1),    # cloud-only: the whole cohort
+    ("qwen3-1.7b", 28, 4, 128, 9, 4),     # testbed edges: a gathered bucket
+])
+def test_cohort_decode_writes_cache_in_place(one_chip, monkeypatch, arch,
+                                             n_layers, slots, max_seq,
+                                             members, bucket):
+    """The cohort dispatch at published widths, with the attention kernel:
+    every stacked cache leaf is donated and aliased to its output, and the
+    entry computation holds no copy, select or fusion of a whole cache leaf
+    — no relayout, merge or copy of the cache at the executable's boundary.
+    (Full depth for qwen3: at two layers its cohort's cache is small enough
+    that the compiler stages it through on-chip memory.)"""
+    import dataclasses
+    from repro.models import lm
+    from repro.serving import fleet
+    cfg = dataclasses.replace(get(arch).config(), n_layers=n_layers)
+    spec = lambda a: _spec(a.shape, a.dtype, one_chip)
+    # the serving path takes the Pallas kernel on a TPU backend; this
+    # process's backend is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0), cfg))
+    state = jax.tree.map(spec, jax.eval_shape(lambda: fleet._stack(cfg, [
+        fleet.FleetState(cache=lm.make_cache(cfg, slots, max_seq),
+                         next_token=jnp.zeros((slots, 1), jnp.int32))]
+        * members)))
+    rows = lambda dt, *s: _spec((bucket,) + s, dt, one_chip)
+    compiled = fleet._cohort_decode_chunk.lower(
+        jax.tree.map(spec, params), cfg, state, rows(jnp.int32, slots),
+        rows(jnp.bool_, slots), rows(jnp.int32), rows(jnp.bool_), 1,
+        -1).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    ops = _entry_ops(hlo)
+    leaf = ",".join(map(str, state.cache.layer[0][0].shape))
+    params_at = {int(a) for op, shape, a in ops
+                 if op == "parameter" and shape == leaf}
+    assert len(params_at) == 2                                   # K and V
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, may-alias\)", hlo.split("\n", 1)[0])}
+    assert params_at <= aliased, (params_at, aliased)
+    whole = [op for op, shape, _ in ops if shape == leaf and op in (
+        "copy", "copy-start", "copy-done", "select", "fusion")]
+    assert not whole, whole
