@@ -9,7 +9,8 @@ process. The benchmark's own runs never run it.
 For each seed: weights from the seed, warm-up, an open-loop window of
 ``--seconds`` at the cell's own load, then, on the same sample of finished
 requests, the gap of what the program served and the gap of what the fp8
-control (``reference.py``) puts first. One JSON line per seed.
+control (the kind's forward in ``reference.py``'s fp8 mode) puts first. One
+JSON line per seed.
 """
 from __future__ import annotations
 

@@ -4,20 +4,31 @@
 the metrics. Everything that belongs to one of them lives in a file of its
 own, found here by that name:
 
-* ``configs/<config>.json`` (the path ``BENCHMARK.json`` gives);
+* ``configs/<config>.json`` (the path ``BENCHMARK.json`` gives), whose
+  every entry under ``architectures`` names its ``"kind"``;
+* ``archs/<kind>.py``, the code of one kind of served architecture:
+  ``model_config(name, sizes)``, the program's config; ``LEAVES``, a rule
+  ``(z, shape) -> value`` for each parameter leaf name it adds beyond
+  ``table``, ``w`` and ``g`` (``deploy.make_params``); ``forward(params,
+  sizes, tokens, mode)``, its plain reference (``reference.py``); and
+  ``prefill_flops``, ``decode_flops`` and ``decode_attention_cost``, its
+  counts (``flops.py``);
 * ``traffic/<mix>.json``, read by ``traffic/generator.py``;
 * ``metrics/<metric>.py``, a reader with ``read(rec) -> float | None``;
 * ``peaks.json``, the chip's peaks keyed by JAX's ``device_kind``.
 
-A later cell, mix, configuration or metric is added by adding files and
-entries, never by editing this module.
+A later cell, mix, configuration, kind or metric is added by adding files
+and entries, never by editing this module.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
+import re
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
@@ -44,7 +55,50 @@ def applies(metric: Dict, cell: str) -> bool:
 
 def load_config(name: str, bench: Dict) -> Dict:
     entry = next(c for c in bench["configs"] if c["name"] == name)
-    return json.loads((REPO / entry["file"]).read_text())
+    path = REPO / entry["file"]
+    config = json.loads(path.read_text())
+    kinds_of(config["architectures"], str(path))
+    return config
+
+
+@functools.lru_cache(maxsize=None)
+def _module(path: Path) -> ModuleType:
+    """One module object per file, so that a jitted function that takes one
+    of its functions as a static argument compiles once per process."""
+    stem = path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_kind(kind: str) -> ModuleType:
+    """The module ``archs/<kind>.py``; a name that is not a file there
+    raises, naming the path."""
+    path = BENCH_DIR / "archs" / f"{kind}.py"
+    if not re.fullmatch(r"[A-Za-z0-9_][A-Za-z0-9_.-]*", kind) \
+            or not path.is_file():
+        raise FileNotFoundError(f"no module {path} for kind {kind!r}")
+    return _module(path)
+
+
+def kinds_of(archs: Dict[str, Dict], where: str) -> Dict[str, ModuleType]:
+    """The kind module of each served architecture; ``where`` names the
+    sizes' origin in the error raised for an entry that names no kind, or a
+    kind with no module."""
+    out = {}
+    for name, sizes in archs.items():
+        if "kind" not in sizes:
+            raise ValueError(f"{where}: architecture {name!r} names no "
+                             f"\"kind\" (the module archs/<kind>.py that "
+                             f"builds it)")
+        try:
+            out[name] = load_kind(sizes["kind"])
+        except FileNotFoundError as e:
+            raise FileNotFoundError(f"{where}: architecture {name!r}: {e}") \
+                from None
+    return out
 
 
 def load_traffic(name: str) -> Dict:
@@ -67,12 +121,7 @@ def load_cell(name: str, bench: Optional[Dict] = None) -> Cell:
 
 
 def load_reader(metric: str) -> Callable[[Dict], Optional[float]]:
-    path = BENCH_DIR / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{metric.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(BENCH_DIR / "metrics" / f"{metric}.py").read
 
 
 def load_peak(device_kind: str) -> Dict:

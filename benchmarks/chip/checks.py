@@ -3,7 +3,8 @@
 Once the window has closed, a sample of the requests it finished is drawn
 from the seed for each served architecture, always with the one that served
 the most tokens in it. For each request the reference runs once over its
-prompt (as the engine was handed it) followed by its served tokens, and
+prompt (as the engine was handed it) followed by its served tokens, in the
+forward pass of the architecture's kind (``archs/<kind>.py``), and
 reads, at every position that produced a served token, how far that token's
 logit lies below the reference's best. The widest such gap per architecture
 is held to the configuration's limit (``check.logit_gap_max``).
@@ -75,13 +76,14 @@ def gaps(dep: deploy.Deployment, rows, spec: Dict, arch: str,
     """The gaps of every compared position of ``rows`` (served tokens, or
     with ``control`` the fp8 control's first choice)."""
     tok, tgt, msk = batch(rows, spec["sample_requests"], spec["pad_to"])
-    a = reference.arch(dep.archs[arch])
+    fwd, sizes = dep.kinds[arch].forward, reference.Sizes(dep.archs[arch])
     p = dep.params[arch]
     if control:
-        g = reference.control_gaps(p, a, jnp.asarray(tok), jnp.asarray(msk))
+        g = reference.control_gaps(p, fwd, sizes, jnp.asarray(tok),
+                                   jnp.asarray(msk))
     else:
-        g = reference.served_gaps(p, a, jnp.asarray(tok), jnp.asarray(tgt),
-                                  jnp.asarray(msk))
+        g = reference.served_gaps(p, fwd, sizes, jnp.asarray(tok),
+                                  jnp.asarray(tgt), jnp.asarray(msk))
     return np.asarray(jax.device_get(g))[msk]
 
 

@@ -10,12 +10,14 @@ from typing import Dict
 from benchmarks.chip.cell import Cell, load_cell
 
 ARCHS = {
-    "stablelm-3b": dict(n_layers=4, d_model=128, n_heads=4, n_kv_heads=4,
-                        head_dim=32, d_ff=352, vocab=512, rope_theta=1e4,
-                        norm_eps=1e-5, qk_norm=False, dtype="bfloat16"),
-    "qwen3-1.7b": dict(n_layers=4, d_model=128, n_heads=4, n_kv_heads=2,
-                       head_dim=32, d_ff=256, vocab=512, rope_theta=1e6,
-                       norm_eps=1e-5, qk_norm=True, dtype="bfloat16"),
+    "stablelm-3b": dict(kind="dense", n_layers=4, d_model=128, n_heads=4,
+                        n_kv_heads=4, head_dim=32, d_ff=352, vocab=512,
+                        rope_theta=1e4, norm_eps=1e-5, qk_norm=False,
+                        dtype="bfloat16"),
+    "qwen3-1.7b": dict(kind="dense", n_layers=4, d_model=128, n_heads=4,
+                       n_kv_heads=2, head_dim=32, d_ff=256, vocab=512,
+                       rope_theta=1e6, norm_eps=1e-5, qk_norm=True,
+                       dtype="bfloat16"),
 }
 PEAK = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
 

@@ -2,15 +2,17 @@
 genome, the ``ClusterServer``, and a warm-up of every shape the window uses.
 
 Both configurations route with the threshold policy at the paper's default
-genome. The weights are the benchmark's, not the program's: one jitted call
-per model makes every leaf of the program's parameter layout on the device,
-in the type it is served in, from ``--seed``. The reference reads the same
-arrays.
+genome. Each served architecture's kind (``archs/<kind>.py``) gives the
+program's config for its sizes. The weights are the benchmark's, not the
+program's: one jitted call per model makes every leaf of the program's
+parameter layout on the device, in the type it is served in, from
+``--seed``. The reference reads the same arrays.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
+from types import ModuleType
 from typing import Dict, List, Optional
 
 import jax
@@ -24,6 +26,7 @@ from repro.models.config import ModelConfig
 from repro.serving import ClusterServer, EngineConfig, ServeRequest
 from repro.workload.datasets import Request
 
+from benchmarks.chip.cell import kinds_of
 from benchmarks.chip.traffic import generator
 
 TESTBEDS = {"paper_testbed": paper_testbed}
@@ -35,22 +38,11 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.key(seed & 0xFFFFFFFF), seed >> 32)
 
 
-def model_config(name: str, a: Dict) -> ModelConfig:
-    """The program's config for the sizes a configuration file states."""
-    return ModelConfig(
-        name=name, family="dense", n_layers=a["n_layers"],
-        d_model=a["d_model"], n_heads=a["n_heads"],
-        n_kv_heads=a["n_kv_heads"], d_ff=a["d_ff"], vocab=a["vocab"],
-        pattern=(("attn", "dense"),), head_dim=a["head_dim"],
-        qk_norm=a["qk_norm"], rope_theta=a["rope_theta"], norm="rmsnorm",
-        activation="swiglu", tie_embeddings=False, norm_eps=a["norm_eps"],
-        dtype=a["dtype"])
-
-
-def make_params(cfg: ModelConfig, key):
+def make_params(cfg: ModelConfig, key, kind: ModuleType):
     """Every leaf of ``lm.init``'s layout, made in one jitted call: an
     embedding or output table N(0, 0.02^2), a weight matrix N(0, 1/fan_in),
-    a norm gain 1 + N(0, 0.1^2)."""
+    a norm gain 1 + N(0, 0.1^2), and any other leaf by the kind's rule for
+    its name (``kind.LEAVES``), each from the same N(0, 1) draw."""
     shapes = jax.eval_shape(lambda k: lm.init(k, cfg), jax.random.key(0))
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
 
@@ -66,6 +58,8 @@ def make_params(cfg: ModelConfig, key):
                 v = z / math.sqrt(sd.shape[-2])
             elif name == "g":
                 v = 1.0 + 0.1 * z
+            elif name in kind.LEAVES:
+                v = kind.LEAVES[name](z, sd)
             else:
                 raise ValueError(f"unknown parameter leaf {path}")
             out.append(v.astype(sd.dtype))
@@ -93,6 +87,7 @@ def cluster_of(spec: Dict) -> ClusterSpec:
 class Deployment:
     srv: ClusterServer
     archs: Dict[str, Dict]          # served architecture -> sizes
+    kinds: Dict[str, ModuleType]    # served architecture -> archs/<kind>.py
     params: Dict[str, object]       # served architecture -> weights
     pair_arch: Dict[int, str]       # routable pair -> architecture
     captured: Dict                  # (pair, request id) -> prompt token ids
@@ -102,12 +97,14 @@ def build(config: Dict, seed: int,
           archs: Optional[Dict[str, Dict]] = None) -> Deployment:
     """``archs`` replaces the file's sizes (tests pass small ones)."""
     archs = archs or config["architectures"]
+    kinds = kinds_of(archs, f"configuration {config['name']!r}")
     cluster = cluster_of(config["cluster"])
     key = seed_key(seed)
     cfgs, params = {}, {}
     for i, name in enumerate(sorted(archs)):
-        cfgs[name] = model_config(name, archs[name])
-        params[name] = make_params(cfgs[name], jax.random.fold_in(key, i))
+        cfgs[name] = kinds[name].model_config(name, archs[name])
+        params[name] = make_params(cfgs[name], jax.random.fold_in(key, i),
+                                   kinds[name])
     builders = {m: (cfgs[a], params[a])
                 for m, a in config["served_by"].items()}
 
@@ -121,7 +118,7 @@ def build(config: Dict, seed: int,
                         fleet=True)
     pair_arch = {p: config["served_by"][cluster.models[k].name]
                  for p, (_, k) in enumerate(cluster.pairs())}
-    dep = Deployment(srv=srv, archs=archs, params=params,
+    dep = Deployment(srv=srv, archs=archs, kinds=kinds, params=params,
                      pair_arch=pair_arch, captured={})
     for p, eng in srv.engines.items():
         eng.submit = _capturing(eng.submit, dep.captured, p)

@@ -39,7 +39,7 @@ class Probe:
     around calls into the program (``route``, ``admit``, ``step``,
     ``submit``), each also written into the profiler's trace, and, while the
     profiler runs, the model FLOPs and the decode-attention work that the
-    served tokens needed."""
+    served tokens needed, as each architecture's kind counts them."""
 
     def __init__(self, dep: deploy.Deployment, peak: Dict):
         self.dep, self.peak = dep, peak
@@ -69,7 +69,7 @@ class Probe:
 
     def _prefill(self, arch: str, args) -> None:
         if len(args) > 2:
-            self.counts["model_flops"] += flops.prefill_flops(
+            self.counts["model_flops"] += self.dep.kinds[arch].prefill_flops(
                 self.dep.archs[arch], len(args[2]))
 
     def before_step(self) -> None:
@@ -84,9 +84,9 @@ class Probe:
                     kv[self.dep.pair_arch[p]].append(
                         s.prompt_tokens + len(s.generated))
         for arch, lens in kv.items():
-            a = self.dep.archs[arch]
-            f, b = flops.decode_attention_cost(a, lens)
-            self.counts["model_flops"] += flops.decode_flops(a, lens)
+            a, kind = self.dep.archs[arch], self.dep.kinds[arch]
+            f, b = kind.decode_attention_cost(a, lens)
+            self.counts["model_flops"] += kind.decode_flops(a, lens)
             self.counts["attn_min_s"] += flops.roofline_seconds(f, b,
                                                                 self.peak)
 

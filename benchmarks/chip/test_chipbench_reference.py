@@ -15,6 +15,7 @@ REPO = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(REPO), str(REPO / "src")]
 
 from benchmarks.chip import cpu_smoke, deploy, reference  # noqa: E402
+from benchmarks.chip.cell import load_kind  # noqa: E402
 from repro.models import lm  # noqa: E402
 
 PROMPT = [3, 17, 99, 250, 7, 41, 300, 12, 5]
@@ -23,8 +24,9 @@ NEW = 8
 
 def _program(arch: str, dtype: str):
     sizes = dict(cpu_smoke.ARCHS[arch], dtype=dtype)
-    cfg = deploy.model_config(arch, sizes)
-    params = deploy.make_params(cfg, deploy.seed_key(2**33 + 5))
+    kind = load_kind(sizes["kind"])
+    cfg = kind.model_config(arch, sizes)
+    params = deploy.make_params(cfg, deploy.seed_key(2**33 + 5), kind)
     logits, cache = lm.prefill(params, cfg,
                                {"tokens": jnp.asarray([PROMPT])}, max_seq=32)
     steps, served = [logits[0]], []
@@ -40,7 +42,7 @@ def _program(arch: str, dtype: str):
 def test_reference_equals_prefill_then_decode(arch):
     sizes, params, prog, served = _program(arch, "float32")
     seq = jnp.asarray([PROMPT + served])
-    ref = reference.forward(params, reference.arch(sizes), seq)[0]
+    ref = load_kind(sizes["kind"]).forward(params, sizes, seq)[0]
     L = len(PROMPT)
     want = np.asarray(ref[L - 1:L - 1 + NEW])
     scale = float(np.abs(want).max())
@@ -57,14 +59,14 @@ def test_served_gap_is_rounding_and_the_fp8_control_is_wider(arch):
     L = len(PROMPT)
     tgt[0, L - 1:L - 1 + NEW] = served
     mask[0, L - 1:L - 1 + NEW] = True
-    a = reference.arch(sizes)
-    got = np.asarray(reference.served_gaps(params, a, seq, tgt, mask))
-    ctl = np.asarray(reference.control_gaps(params, a, seq, mask))
+    fwd, a = load_kind(sizes["kind"]).forward, reference.Sizes(sizes)
+    got = np.asarray(reference.served_gaps(params, fwd, a, seq, tgt, mask))
+    ctl = np.asarray(reference.control_gaps(params, fwd, a, seq, mask))
     assert (got[~mask] == 0).all() and (ctl[~mask] == 0).all()
     assert got.max() < 0.02
     assert ctl.max() >= 0.0
     # a served token replaced by another lies far below the reference's best
     tgt[0, L - 1] = (tgt[0, L - 1] + 1) % sizes["vocab"]
-    bad = np.asarray(reference.served_gaps(params, a, seq, tgt, mask))
+    bad = np.asarray(reference.served_gaps(params, fwd, a, seq, tgt, mask))
     assert bad.max() > 0.05
     jax.clear_caches()

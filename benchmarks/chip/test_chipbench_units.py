@@ -105,18 +105,19 @@ def _sizes(config: str, arch: str):
 def test_flops_match_hand_counts():
     st, qw = _sizes("testbed", "stablelm-3b"), _sizes("testbed",
                                                       "qwen3-1.7b")
+    dense = cellmod.load_kind(st["kind"])
     # stablelm-3b: 4 x 2560^2 attention + 3 x 2560 x 6912 SwiGLU per layer
-    assert flops.layer_matmul_params(st) == 79_298_560
-    assert flops.token_matmul_flops(st) == \
+    assert dense.layer_matmul_params(st) == 79_298_560
+    assert dense.token_matmul_flops(st) == \
         2 * (32 * 79_298_560 + 50304 * 2560)
     # qwen3-1.7b: q/o 2048x2048, k/v 2048x1024, SwiGLU 3 x 2048 x 6144
-    assert flops.layer_matmul_params(qw) == 50_331_648
-    assert flops.decode_flops(qw, [100]) == \
+    assert dense.layer_matmul_params(qw) == 50_331_648
+    assert dense.decode_flops(qw, [100]) == \
         2 * (28 * 50_331_648 + 151936 * 2048) + 4 * 28 * 2048 * 100
-    f, b = flops.decode_attention_cost(qw, [100, 50])
+    f, b = dense.decode_attention_cost(qw, [100, 50])
     assert f == 4 * 28 * 2048 * 150
     assert b == 2 * 28 * 1024 * 150 * 2 + 2 * 28 * 2 * 16 * 128 * 2
-    assert flops.prefill_flops(st, 24) == (2 * 24 * 32 * 79_298_560
+    assert dense.prefill_flops(st, 24) == (2 * 24 * 32 * 79_298_560
                                            + 2 * 50304 * 2560
                                            + 2 * 32 * 2560 * 24 * 25)
     peak = cellmod.load_peak("TPU v5 lite")
@@ -147,7 +148,7 @@ def test_new_files_are_found_by_name(tmp_path, monkeypatch):
     """A new configuration, mix and metric need only new files and
     BENCHMARK.json entries."""
     bench_dir = tmp_path / "benchmarks" / "chip"
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "traffic", "metrics", "archs"):
         shutil.copytree(cellmod.BENCH_DIR / sub, bench_dir / sub)
     shutil.copy(cellmod.BENCH_DIR / "peaks.json", bench_dir)
     bench = cellmod.load_benchmark()
