@@ -3,7 +3,9 @@ Mamba:attention 1:7 interleave, MoE 16e top-2 every other layer.
 [arXiv:2403.19887; hf]
 
 Jamba block = period 8: one attention layer (index 4), seven Mamba layers;
-MoE replaces the dense MLP on every second layer. Sub-quadratic overall:
+MoE replaces the dense MLP on every second layer, the odd ones
+(``expert_layer_offset`` 1, ``expert_layer_period`` 2 in the published
+config), so the attention layer keeps a dense MLP. Sub-quadratic overall:
 runs the long_500k cell (the 4 attention layers keep KV caches; Mamba
 layers carry O(1) state).
 """
@@ -13,7 +15,7 @@ from ._base import make_card
 NAME = "jamba-v0.1-52b"
 
 _PATTERN = tuple(
-    ("attn" if i == 4 else "mamba", "moe" if i % 2 == 0 else "dense")
+    ("attn" if i == 4 else "mamba", "moe" if i % 2 == 1 else "dense")
     for i in range(8))
 
 

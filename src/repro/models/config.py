@@ -14,7 +14,7 @@ in depth. Heterogeneous families:
 * dense LMs            — pattern [("attn", "dense")]
 * dbrx (all-MoE)       — [("attn", "moe")]
 * llama4 (interleaved) — [("attn", "moe"), ("attn", "dense")]
-* jamba (1:7 + MoE/2)  — period 8, attn at index 4, MoE on even indices
+* jamba (1:7 + MoE/2)  — period 8, attn at index 4, MoE on odd indices
 * xLSTM [7:1]          — 7×("mlstm", "none") + ("slstm", "none")
 * llama-3.2-vision     — period 5, ("cross", "dense") at index 0
 * whisper decoder      — [("attn_cross", "dense")], plus an encoder stack
@@ -37,10 +37,24 @@ FFNS = ("dense", "moe", "none")
 
 @dataclasses.dataclass(frozen=True)
 class MoECfg:
-    n_experts: int
+    n_experts: int                # the router's width: experts of the model
     top_k: int
     d_ff: int                     # per-expert hidden width
     capacity_factor: float = 1.25
+    # [start, stop) of the experts this layer holds (its share under expert
+    # parallelism). None: every expert, through the GShard capacity path;
+    # given: the dropless, row-independent layer (``moe.moe_apply``)
+    held: Optional[Tuple[int, int]] = None
+    renormalize: bool = True      # rescale the top-k weights to sum to 1
+
+    @property
+    def dropless(self) -> bool:
+        return self.held is not None
+
+    @property
+    def n_held(self) -> int:
+        return (self.held[1] - self.held[0] if self.dropless
+                else self.n_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +70,7 @@ class SSMCfg:
     d_conv: int = 4
     expand: int = 2
     chunk: int = 128              # selective-scan chunk length
+    dt_rank: Optional[int] = None  # None: d_model // 16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,6 +94,7 @@ class ModelConfig:
     head_dim: Optional[int] = None
     qk_norm: bool = False
     rope_theta: float = 1e4
+    rope: bool = True             # False: attention has no positional code
     norm: str = "rmsnorm"         # rmsnorm | layernorm
     activation: str = "swiglu"    # swiglu | gelu
     tie_embeddings: bool = False
@@ -160,7 +176,7 @@ class ModelConfig:
             if ffn == "dense":
                 f_tot = f_act = dense_ffn
             elif ffn == "moe":
-                f_tot = 3 * d * self.moe.d_ff * self.moe.n_experts + d * self.moe.n_experts
+                f_tot = 3 * d * self.moe.d_ff * self.moe.n_held + d * self.moe.n_experts
                 f_act = 3 * d * self.moe.d_ff * self.moe.top_k + d * self.moe.n_experts
             else:
                 f_tot = f_act = 0

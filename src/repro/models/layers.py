@@ -81,6 +81,15 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
                             xf2 * cos + xf1 * sin], axis=-1).astype(x.dtype)
 
 
+def rope_qk(cfg: ModelConfig, q, k, positions):
+    """Rotary positions on q and k, or neither where the config has no
+    positional encoding (``cfg.rope`` False: Jamba's attention)."""
+    if not cfg.rope:
+        return q, k
+    return (rope(q, positions, cfg.rope_theta),
+            rope(k, positions, cfg.rope_theta))
+
+
 def embed_init(key, vocab: int, d: int, dtype):
     return {"table": (jax.random.normal(key, (vocab, d), jnp.float32)
                       * 0.02).astype(dtype)}
@@ -198,8 +207,7 @@ def attention(p, cfg: ModelConfig, x, *, positions, causal: bool = True,
     kv_in = x if kv_x is None else kv_x
     q, k, v = _qkv(p, cfg, x, kv_in)
     if kv_x is None:  # self-attention gets RoPE
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q, k = rope_qk(cfg, q, k, positions)
     if use_pallas in ("pallas", "interpret") or (
             use_pallas == "auto" and jax.default_backend() == "tpu"):
         out = kops.flash_attention(jnp.swapaxes(q, 1, 2),
@@ -219,8 +227,7 @@ def attention_prefill_cache(p, cfg: ModelConfig, x, positions
                             ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Like attention() but also returns the (k, v) cache (B, S, Hkv, D)."""
     q, k, v = _qkv(p, cfg, x, x)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q, k = rope_qk(cfg, q, k, positions)
     out = _xla_attention(q, k, v, causal=True)
     B, S = x.shape[:2]
     y = jnp.einsum("bse,ed->bsd", out.reshape(B, S, cfg.q_dim), p["wo"]["w"])
@@ -252,8 +259,7 @@ def attention_prefill_extend(p, cfg: ModelConfig, x, positions, prefix_kv,
     k_pre, v_pre = prefix_kv
     S_pre = k_pre.shape[1]
     q, k, v = _qkv(p, cfg, x, x)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q, k = rope_qk(cfg, q, k, positions)
     k_full = jnp.concatenate([k_pre, k], axis=1)
     v_full = jnp.concatenate([v_pre, v], axis=1)
     B, S = x.shape[:2]
@@ -287,8 +293,9 @@ def attention_decode(p, cfg: ModelConfig, x, cache, pos, layer,
     is 16 MiB)."""
     B = x.shape[0]
     q, k_new, v_new = _qkv(p, cfg, x, x)
-    q = rope(q, pos[:, None], cfg.rope_theta)
-    k_new = rope(k_new, pos[:, None], cfg.rope_theta)
+    if cfg.rope:
+        q = rope(q, pos[:, None], cfg.rope_theta)
+        k_new = rope(k_new, pos[:, None], cfg.rope_theta)
     q = constrain(q, "dp", None, None, "model")
     k_new = constrain(k_new, "dp", None, None, "model")
     v_new = constrain(v_new, "dp", None, None, "model")

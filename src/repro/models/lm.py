@@ -151,8 +151,9 @@ def init(key, cfg: ModelConfig) -> Params:
 
 def _apply_block_full(p, cfg: ModelConfig, mixer: str, ffn: str, x, *,
                       positions, cross_x, causal: bool, use_pallas: str,
-                      collect_cache: bool):
-    """Returns (x, cache_entry, aux)."""
+                      collect_cache: bool, length=None):
+    """Returns (x, cache_entry, aux). ``length``: the true length of a
+    padded prompt, which a recurrent mixer's state stops at."""
     aux = jnp.float32(0.0)
     cache = ()
     h = L.apply_norm(cfg, p["norm1"], x)
@@ -185,7 +186,7 @@ def _apply_block_full(p, cfg: ModelConfig, mixer: str, ffn: str, x, *,
         if collect_cache:
             cache = (kv, ckv)
     elif mixer == "mamba":
-        y, st = S.mamba_forward(p["mixer"], cfg, h)
+        y, st = S.mamba_forward(p["mixer"], cfg, h, length=length)
         if collect_cache:
             cache = st
         x = x + y
@@ -219,7 +220,8 @@ REMAT_POLICIES = {
 
 def _backbone_full(params, cfg: ModelConfig, x, *, positions, cross_x,
                    causal=True, use_pallas="auto", collect_cache=False,
-                   remat=True, unroll=False, remat_policy="full"):
+                   remat=True, unroll=False, remat_policy="full",
+                   length=None):
     """Run the block pattern over periods: ``lax.scan`` by default (O(1) HLO
     in depth), or a Python loop with ``unroll=True`` — used by the dry-run so
     ``cost_analysis`` sees every period (XLA counts while bodies once).
@@ -233,7 +235,7 @@ def _backbone_full(params, cfg: ModelConfig, x, *, positions, cross_x,
             x, c, a = _apply_block_full(
                 stacked_slice[i], cfg, mixer, ffn, x, positions=positions,
                 cross_x=cross_x, causal=causal, use_pallas=use_pallas,
-                collect_cache=collect_cache)
+                collect_cache=collect_cache, length=length)
             caches.append(c)
             aux = aux + a
         return x, (tuple(caches), aux)
@@ -349,9 +351,10 @@ def prefill(params, cfg: ModelConfig, batch, max_seq: Optional[int] = None,
     true length — logits are read at row ``length - 1`` and the cache's
     ``kv_len``/``pos`` marks only the real prompt as valid, so the padded
     tail (whose K/V rows land beyond ``kv_len`` and get overwritten by
-    decode) cannot perturb outputs. Exact for pure-attention *dense*
-    patterns (causal masking keeps rows independent); recurrent mixers
-    integrate every token and MoE capacity lets padding displace real
+    decode) cannot perturb outputs. Exact where rows are independent and
+    state stops at the length: attention (causal masking), Mamba (padding
+    is state-neutral, ``ssm.mamba_forward``) and dropless MoE; xLSTM
+    integrates every token and MoE capacity lets padding displace real
     tokens from expert slots, so callers must not pad those (the serving
     engine gates on the block pattern)."""
     tokens = batch["tokens"]
@@ -363,7 +366,7 @@ def prefill(params, cfg: ModelConfig, batch, max_seq: Optional[int] = None,
     x, caches, _ = _backbone_full(params, cfg, x, positions=positions,
                                   cross_x=cross_x, use_pallas=use_pallas,
                                   collect_cache=True, remat=False,
-                                  unroll=unroll)
+                                  unroll=unroll, length=length)
     x = (L.apply_norm(cfg, params["final_norm"], x) if cfg.norm == "rmsnorm"
          else L.layer_norm(params["final_norm"], x, cfg.norm_eps))
     head = params.get("head", params["embed"])
@@ -562,7 +565,9 @@ def _apply_block_decode(p, cfg: ModelConfig, mixer: str, ffn: str, x,
                         cache_entry, cross_entry, kv_len, layer, use_pallas):
     """x: (B, 1, d). ``cache_entry`` is, for a K/V mixer, the (k, v) of every
     period, written at period ``layer``; for a recurrent mixer, this period's
-    state. Returns (x, new_cache_entry)."""
+    state. Returns (x, new_cache_entry, picks): ``picks`` (B,) counts each
+    row's top-k picks among the experts an MoE block holds, None for other
+    blocks."""
     h = L.apply_norm(cfg, p["norm1"], x)
     new_entry = cache_entry
     if mixer in _KV_MIXERS:
@@ -588,18 +593,24 @@ def _apply_block_decode(p, cfg: ModelConfig, mixer: str, ffn: str, x,
         new_entry = st
         x = x + y
 
+    picks = None
     if ffn == "dense":
         x = x + L.ffn_apply(p["ffn"], L.apply_norm(cfg, p["norm2"], x),
                             activation=cfg.activation)
     elif ffn == "moe":
-        y, _ = M.moe_apply(p["ffn"], cfg, L.apply_norm(cfg, p["norm2"], x))
+        y, _, picks = M.moe_apply(p["ffn"], cfg,
+                                  L.apply_norm(cfg, p["norm2"], x),
+                                  with_picks=True)
         x = x + y
-    return x, new_entry
+        picks = picks[:, 0]
+    return x, new_entry, picks
 
 
 def decode_step(params, cfg: ModelConfig, token, cache: Cache,
-                use_pallas="auto", unroll=False):
-    """token: (B, 1) int32. Returns (logits (B, vocab), new Cache).
+                use_pallas="auto", unroll=False, expert_rows=False):
+    """token: (B, 1) int32. Returns (logits (B, vocab), new Cache), and with
+    ``expert_rows`` a third output: (B,) int32, each row's top-k picks
+    among the held experts, summed over the MoE blocks.
 
     The K/V of attention positions stay stacked over periods in the period
     loop's carry: each period writes the new token's K/V at its own index,
@@ -612,32 +623,37 @@ def decode_step(params, cfg: ModelConfig, token, cache: Cache,
     state = tuple(() if a else c for a, c in zip(is_kv, cache.layer))
 
     def period_body(carry, sl):
-        x, kv, layer = carry
+        x, kv, layer = carry[:3]
+        rows = carry[3:]
         stacked, state_c, cross_c = sl
         kv, new_state = list(kv), []
         x = constrain(x, "dp", None, None)
         for i, (mixer, ffn) in enumerate(cfg.pattern):
-            x, nc = _apply_block_decode(
+            x, nc, picks = _apply_block_decode(
                 stacked[i], cfg, mixer, ffn, x,
                 kv[i] if is_kv[i] else state_c[i], cross_c[i],
                 cache.kv_len, layer, use_pallas)
             if is_kv[i]:
                 kv[i] = nc
             new_state.append(() if is_kv[i] else nc)
-        return (x, tuple(kv), layer + 1), tuple(new_state)
+            if rows and picks is not None:
+                rows = (rows[0] + picks,)
+        return (x, tuple(kv), layer + 1) + rows, tuple(new_state)
 
     xs = (params["blocks"], state, cache.cross)
+    rows0 = ((jnp.zeros(token.shape[:1], jnp.int32),) if expert_rows
+             else ())
     if unroll:
-        carry, per_period = (x, kv, 0), []
+        carry, per_period = (x, kv, 0) + rows0, []
         for pi in range(cfg.n_periods):
             carry, ncs = period_body(carry,
                                      jax.tree.map(lambda a: a[pi], xs))
             per_period.append(ncs)
         new_state = jax.tree.map(lambda *xs: jnp.stack(xs), *per_period)
     else:
-        carry, new_state = jax.lax.scan(period_body, (x, kv, jnp.int32(0)),
-                                        xs)
-    x, kv, _ = carry
+        carry, new_state = jax.lax.scan(
+            period_body, (x, kv, jnp.int32(0)) + rows0, xs)
+    x, kv = carry[:2]
     x = (L.apply_norm(cfg, params["final_norm"], x) if cfg.norm == "rmsnorm"
          else L.layer_norm(params["final_norm"], x, cfg.norm_eps))
     head = params.get("head", params["embed"])
@@ -646,4 +662,6 @@ def decode_step(params, cfg: ModelConfig, token, cache: Cache,
                       for a, k, st in zip(is_kv, kv, new_state))
     new_cache = cache._replace(layer=new_layer, kv_len=cache.kv_len + 1,
                                pos=cache.pos + 1)
+    if expert_rows:
+        return logits, new_cache, carry[3]
     return logits, new_cache

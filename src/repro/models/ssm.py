@@ -15,6 +15,16 @@ adapted for TPU:
   row-local in d_inner — one all-reduce per block, Megatron-style.
 
 Decode is the O(1) recurrent step; its state is (h, conv window).
+
+As in transformers' ``modeling_jamba.py``, dt, B and C are RMSNormed after
+``x_proj`` (its ``dt_layernorm``, ``b_layernorm`` and ``c_layernorm``);
+``SSMCfg.dt_rank`` states the rank of dt where the published config gives
+one.
+
+A prefill given the prompt's true ``length`` (a traced value) may run on
+tokens padded past it: positions at or past the length are state-neutral
+(Δt = 0, so a = 1 and b = 0) and the conv window is read ending at the
+length, so a bucket-padded prompt leaves the exact-length state.
 """
 from __future__ import annotations
 
@@ -25,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from .config import ModelConfig
-from .layers import _dtype, dense_init
+from .layers import _dtype, dense_init, norm_init, rms_norm
 from .sharding import constrain
 
 Params = Dict
@@ -36,7 +46,7 @@ def d_inner(cfg: ModelConfig) -> int:
 
 
 def dt_rank(cfg: ModelConfig) -> int:
-    return max(1, cfg.d_model // 16)
+    return cfg.ssm.dt_rank or max(1, cfg.d_model // 16)
 
 
 def mamba_init(key, cfg: ModelConfig) -> Params:
@@ -47,6 +57,8 @@ def mamba_init(key, cfg: ModelConfig) -> Params:
     # S4D-real initialization for A
     A = jnp.tile(jnp.arange(1, ds + 1, dtype=jnp.float32)[None, :], (di, 1))
     return {
+        "dt_norm": norm_init(dr), "b_norm": norm_init(ds),
+        "c_norm": norm_init(ds),
         "in_proj": dense_init(ks[0], cfg.d_model, 2 * di, dt),
         "conv": {"w": (jax.random.normal(ks[1], (s.d_conv, di), jnp.float32)
                        / math.sqrt(s.d_conv)).astype(dt),
@@ -65,8 +77,12 @@ def _ssm_params(p, cfg: ModelConfig, xc):
     """xc: (B, L, di) post-conv activations -> (dtv, Bv, Cv) f32."""
     ds = cfg.ssm.d_state
     dr = dt_rank(cfg)
-    proj = jnp.einsum("bld,de->ble", xc, p["x_proj"]["w"]).astype(jnp.float32)
+    proj = jnp.einsum("bld,de->ble", xc, p["x_proj"]["w"],
+                      preferred_element_type=jnp.float32)
     dt_in, Bv, Cv = jnp.split(proj, [dr, dr + ds], axis=-1)
+    dt_in = rms_norm(p["dt_norm"], dt_in, cfg.norm_eps)
+    Bv = rms_norm(p["b_norm"], Bv, cfg.norm_eps)
+    Cv = rms_norm(p["c_norm"], Cv, cfg.norm_eps)
     dtv = jax.nn.softplus(
         jnp.einsum("blr,rd->bld", dt_in, p["dt_proj"]["w"].astype(jnp.float32))
         + p["dt_proj"]["b"])
@@ -98,12 +114,14 @@ def _scan_chunked(a, b, h0, chunk: int):
     return h_last, hs
 
 
-def mamba_forward(p, cfg: ModelConfig, x, state=None
+def mamba_forward(p, cfg: ModelConfig, x, state=None, length=None
                   ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
     """Full-sequence forward. x: (B, L, d). Returns (y, (h, conv_win)).
 
     L is padded up to a chunk multiple with *state-neutral* steps
-    (Δt = 0 ⇒ a = 1, b = 0), so the returned state is exact at position L.
+    (Δt = 0 ⇒ a = 1, b = 0), so the returned state is exact at position L;
+    with ``length`` (traced, <= L) every position from ``length`` on is
+    state-neutral too and the state is the one at ``length``.
     """
     s = cfg.ssm
     B, L0, _ = x.shape
@@ -119,30 +137,36 @@ def mamba_forward(p, cfg: ModelConfig, x, state=None
     z = constrain(z, "dp", None, "model")
 
     # causal depthwise conv (window d_conv)
-    if state is not None:
-        conv_win = state[1]                          # (B, d_conv-1, di)
-        xpad = jnp.concatenate([conv_win, xi], axis=1)
-    else:
-        xpad = jnp.pad(xi, ((0, 0), (s.d_conv - 1, 0), (0, 0)))
-    xc = sum(xpad[:, i:i + L] * p["conv"]["w"][i] for i in range(s.d_conv))
-    xc = jax.nn.silu((xc + p["conv"]["b"]).astype(jnp.float32)).astype(x.dtype)
-    new_conv_win = jax.lax.dynamic_slice_in_dim(xpad, L0, s.d_conv - 1, 1)
+    with jax.named_scope("mamba.conv"):
+        if state is not None:
+            conv_win = state[1]                      # (B, d_conv-1, di)
+            xpad = jnp.concatenate([conv_win, xi], axis=1)
+        else:
+            xpad = jnp.pad(xi, ((0, 0), (s.d_conv - 1, 0), (0, 0)))
+        xc = sum(xpad[:, i:i + L] * p["conv"]["w"][i]
+                 for i in range(s.d_conv))
+        xc = jax.nn.silu(
+            (xc + p["conv"]["b"]).astype(jnp.float32)).astype(x.dtype)
+        new_conv_win = jax.lax.dynamic_slice_in_dim(
+            xpad, L0 if length is None else length, s.d_conv - 1, 1)
 
-    dtv, Bv, Cv = _ssm_params(p, cfg, xc)            # f32
-    if pad:
-        valid = (jnp.arange(L) < L0)[None, :, None]
-        dtv = jnp.where(valid, dtv, 0.0)             # a=1, b=0 on pad steps
-    A = -jnp.exp(p["A_log"])                         # (di, ds)
-    a = jnp.exp(dtv[..., None] * A[None, None])      # (B, L, di, ds)
-    bterm = (dtv * xc.astype(jnp.float32))[..., None] * Bv[:, :, None, :]
+    with jax.named_scope("mamba.ssm"):
+        dtv, Bv, Cv = _ssm_params(p, cfg, xc)        # f32
+        if pad or length is not None:
+            valid = (jnp.arange(L) < (L0 if length is None else length))
+            # a=1, b=0 on steps past the prompt
+            dtv = jnp.where(valid[None, :, None], dtv, 0.0)
+        A = -jnp.exp(p["A_log"])                     # (di, ds)
+        a = jnp.exp(dtv[..., None] * A[None, None])  # (B, L, di, ds)
+        bterm = (dtv * xc.astype(jnp.float32))[..., None] * Bv[:, :, None, :]
 
-    h0 = (state[0] if state is not None
-          else jnp.zeros((B, di, s.d_state), jnp.float32))
-    chunk = min(s.chunk, L)
-    assert L % chunk == 0, (L, chunk)
-    h_last, hs = _scan_chunked(a, bterm, h0, chunk)
-
-    y = jnp.einsum("blds,bls->bld", hs, Cv) + p["D"] * xc.astype(jnp.float32)
+        h0 = (state[0] if state is not None
+              else jnp.zeros((B, di, s.d_state), jnp.float32))
+        chunk = min(s.chunk, L)
+        assert L % chunk == 0, (L, chunk)
+        h_last, hs = _scan_chunked(a, bterm, h0, chunk)
+        y = (jnp.einsum("blds,bls->bld", hs, Cv)
+             + p["D"] * xc.astype(jnp.float32))
     y = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
     out = constrain(jnp.einsum("bld,de->ble", y, p["out_proj"]["w"]),
                     "dp", None, None)

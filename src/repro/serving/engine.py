@@ -92,7 +92,8 @@ def _decode_chunk(params, cfg: ModelConfig, tok, cache, budget, alive,
     decoding garbage that nothing reads — so the chunk is bit-identical to
     ``n`` single steps when no admission happens in between. Emits one
     stacked (n, 3, B) int32 tensor (token, emitted-this-iter, retired-this-
-    iter) so the caller needs a single device->host transfer per chunk.
+    iter; an MoE pattern adds a fourth row of expert picks) so the caller
+    needs a single device->host transfer per chunk.
 
     The scan body lives in ``serving.fleet.decode_chunk_body`` — the same
     code is vmapped over a node axis by ``fleet._cohort_decode_chunk`` so a
@@ -164,12 +165,14 @@ class LLMEngine:
         self.host_syncs = 0   # device->host transfer count (decode path)
         self.decode_dispatches = 0   # jitted decode calls issued by THIS engine
         self.tokens_emitted = 0
-        # bucketed prefill is exact only for pure-attention dense patterns:
-        # recurrent mixers integrate padding tokens into their state, and
-        # MoE capacity (GShard-style drop) lets padding tokens displace
-        # real tokens from expert slots
+        # bucketed prefill is exact where padding cannot reach the prompt's
+        # rows or state: attention (causal), Mamba (state-neutral past the
+        # true length) and dropless MoE; xLSTM integrates padding tokens
+        # into its state, and MoE capacity (GShard-style drop) lets padding
+        # tokens displace real tokens from expert slots
         self._bucket_ok = (ecfg.prefill_bucket > 0 and
-                           all(m == "attn" and f != "moe"
+                           all(m in ("attn", "mamba")
+                               and (f != "moe" or cfg.moe.dropless)
                                for m, f in cfg.pattern))
         self.kv: Optional[PagedKVStore] = (
             PagedKVStore(cfg, ecfg.cache_blocks, ecfg.block_size)
@@ -197,8 +200,10 @@ class LLMEngine:
         """Fleet vectorization is exact only when batch rows are independent:
         a cohort dispatch may overrun a member's committed iterations
         (``n_f > n_eff``), mutating dead-slot rows the per-engine path never
-        touched — invisible unless MoE expert capacity couples rows."""
-        return all(f != "moe" for _, f in self.cfg.pattern)
+        touched — invisible unless MoE expert capacity couples rows. A
+        dropless expert layer keeps them independent."""
+        return all(f != "moe" or self.cfg.moe.dropless
+                   for _, f in self.cfg.pattern)
 
     def _attach_fleet(self, cohort, member: int) -> None:
         """Adopt this engine into a fleet cohort: device state moves into

@@ -36,9 +36,17 @@ Byte-identity: a cohort dispatch runs ``n_f = max`` over the participating
 members' clipped chunk lengths, but each member commits only its own
 ``n_eff`` iterations host-side — device state past a member's ``n_eff``
 touches only slots that are already dead (all ops are row-independent for
-the no-MoE patterns ``LLMEngine.fleet_ok`` admits), and admission rewrites a
+the patterns ``LLMEngine.fleet_ok`` admits: no MoE, or dropless MoE, whose
+every held expert runs over every row alone), and admission rewrites a
 slot's rows wholesale, so fleet stepping reproduces per-engine ``step()`` /
-``step_n()`` bit-for-bit. ``tests/test_fleet.py`` enforces this across every
+``step_n()`` bit-for-bit. Recurrent rows (a Mamba layer's ``(h, conv
+window)``) ride the stacked state like K/V rows, on the same slot axis: a
+dead row's state goes on advancing on garbage that nothing reads, and
+admission's splice overwrites both of its leaves, so the next request in
+that slot starts from its own prefill's state, as on the per-engine path.
+(XLA's CPU backend can round a Mamba state update of the vmapped program
+one float32 ulp away from the per-engine program's; the served tokens and
+step stamps of ``tests/test_jamba2.py`` are identical.) ``tests/test_fleet.py`` enforces this across every
 registered routing policy, disaggregated KV handoffs and node failures.
 """
 from __future__ import annotations
@@ -76,12 +84,20 @@ def decode_chunk_body(params, cfg: ModelConfig, tok, cache, budget, alive,
     slot retires on exhausted budget or EOS (its ``kv_len`` zeroes and its
     next token resets, exactly like ``_release_slot``), and already-dead
     slots keep decoding garbage that nothing reads. Emits one stacked
-    (n, 3, B) int32 tensor (token, emitted-this-iter, retired-this-iter)."""
+    (n, 3, B) int32 tensor (token, emitted-this-iter, retired-this-iter);
+    an MoE pattern adds a fourth row, each emitting slot's top-k picks among
+    the held experts summed over the MoE blocks (0 for a slot that did not
+    emit), so the count rides the same transfer."""
+    moe = any(f == "moe" for _, f in cfg.pattern)
 
     def body(carry, _):
         tok, cache, budget, alive = carry
         with jax.named_scope("decode.step"):
-            logits, cache = lm.decode_step(params, cfg, tok, cache)
+            if moe:
+                logits, cache, picks = lm.decode_step(params, cfg, tok, cache,
+                                                      expert_rows=True)
+            else:
+                logits, cache = lm.decode_step(params, cfg, tok, cache)
         with jax.named_scope("decode.sample"):
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             emit = alive
@@ -90,8 +106,10 @@ def decode_chunk_body(params, cfg: ModelConfig, tok, cache, budget, alive,
             alive = alive & ~retire
             cache = cache._replace(kv_len=jnp.where(retire, 0, cache.kv_len))
             tok = jnp.where(retire, 0, nxt)[:, None]
-            out = jnp.stack([nxt, emit.astype(jnp.int32),
-                             retire.astype(jnp.int32)])
+            out = [nxt, emit.astype(jnp.int32), retire.astype(jnp.int32)]
+            if moe:
+                out.append(jnp.where(emit, picks, 0))
+            out = jnp.stack(out)
         return (tok, cache, budget, alive), out
 
     (tok, cache, budget, alive), outs = jax.lax.scan(
@@ -399,6 +417,8 @@ class Cohort:
             tl.count("cohort.rows_decoded", k_b * B * n_f)
             tl.count("cohort.tokens_emitted", int(emitted.sum()))
             tl.count("cohort.members_gathered", 0 if k_b == M else k_b)
+            if outs_np.shape[2] > 3:     # an MoE pattern's picks row
+                tl.count("moe.expert_rows", int(outs_np[:k, :, 3, :].sum()))
         work = {m: ChunkWork(outs=outs_np[r], n_eff=n_eff[m],
                              active=tuple(work_slots[m]))
                 for r, m in enumerate(parts)}
@@ -407,7 +427,8 @@ class Cohort:
 
 def build_cohorts(engines: Dict[int, object], timeline=NOOP_TIMELINE):
     """Group engines into cohorts by shared (ModelConfig, EngineConfig,
-    params-identity); non-vectorizable engines (MoE patterns) stay loose.
+    params-identity); non-vectorizable engines (capacity-MoE patterns) stay
+    loose.
     Every cohort records into ``timeline``.
 
     Returns ``(cohorts, cohort_pairs, pair_to_cohort)`` where
